@@ -5,13 +5,14 @@ system of Fig. 4, with *real numerics* (mixed-precision expert substitution
 actually changes the computed logits; accuracy benchmarks measure that).
 
 Scope: decoder-only MoE models whose body layers are all (attn + MoE FFN) —
-the paper's model class (Mixtral / Phi-MoE shapes, smoke-scaled here).
+the paper's model class (Mixtral / Phi-MoE shapes).
 
-On this CPU-only container "device" and "host" share silicon, so wall-clock
-transfer times are meaningless; the engine therefore (a) performs the real
-cache/loader mechanics and numerics, and (b) records a routing trace that
-core.simulator replays against hardware cost models for latency numbers —
-the same separation the paper uses for its Fig. 9 analysis.
+The engine performs the real cache/loader mechanics and numerics on
+whatever backend JAX runs: on a TPU the pools live in HBM and the Pallas
+kernels run compiled; on the CPU backend "device" and "host" share memory,
+so only counts and numerics mean anything there.  It also records a routing
+trace that core.simulator replays against hardware cost models — the
+separation the paper uses for its Fig. 9 analysis.
 """
 
 from __future__ import annotations
@@ -40,10 +41,30 @@ from repro.models.model import Batch, Model
 from repro.quant.quantize import QTensor, dequantize, expert_nbytes, quantize
 
 
-def _np_qtensor(q: QTensor) -> QTensor:
-    """Move a QTensor's leaves to host numpy (read-only expert storage)."""
-    return QTensor(data=np.asarray(q.data), scale=np.asarray(q.scale),
-                   bits=q.bits, group_size=q.group_size, orig_k=q.orig_k)
+def _without_experts(params):
+    """The model params with every MoE layer's routed experts left out."""
+    def strip(layer):
+        ffn = layer.get("ffn")
+        if not isinstance(ffn, dict) or "experts" not in ffn:
+            return layer
+        return {**layer, "ffn": {k: v for k, v in ffn.items()
+                                 if k != "experts"}}
+    return {**params, **{part: [strip(p) for p in params[part]]
+                         for part in ("prefix", "blocks", "tail")}}
+
+
+def _expert_refs(cfg, params):
+    """Per layer, (its routed-expert leaves or None, the leading index of
+    its block in a stacked leaf): nothing is sliced, so nothing is copied."""
+    def refs(layer, lead):
+        ffn = layer.get("ffn")
+        experts = ffn.get("experts") if isinstance(ffn, dict) else None
+        return experts, lead
+    out = [refs(p, ()) for p in params["prefix"]]
+    for i in range(cfg.num_blocks):
+        out.extend(refs(p, (i,)) for p in params["blocks"])
+    out.extend(refs(p, ()) for p in params["tail"])
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,11 +107,12 @@ class EngineConfig:
     # serves lo for its own step only and the next step's hi request
     # blocking-loads hi on demand.
     upgrade: bool = True
-    # modeled H2D link bandwidth in GB/s.  None measures the host copy rate
-    # at startup (budget accounting only); an explicit value additionally
-    # *emulates* the link — each staged copy occupies its stream for
-    # bytes/link seconds — so contended-link behavior is measurable on this
-    # CPU-only container (benchmarks/decode_speedup.py uses this).
+    # modeled H2D link bandwidth in GB/s.  None measures the backend's
+    # host->device copy rate at startup (budget accounting only); an
+    # explicit value additionally *emulates* the link — each staged copy
+    # occupies its stream for bytes/link seconds — so contended-link
+    # behavior is reproducible on any backend (benchmarks/decode_speedup.py
+    # uses this).
     link_gbps: Optional[float] = None
     # paged KV cache: slots draw kv_page_size-token pages from a shared pool
     # of kv_pages pages (None = the dense equivalent, batch*ceil(max_len/
@@ -106,16 +128,6 @@ class EngineConfig:
     prefix_sharing: bool = True
 
 
-def pad_pow2(pairs):
-    """Repeat the last (slot, buffer) pair up to a power-of-two count: the
-    duplicate write is idempotent and caps commit-scatter retraces at
-    log(pool) shapes.  Module-level so the trace-time auditor
-    (tools/analysis/entrypoints.py) builds its variant-budget shape set with
-    the exact padding the production commit path uses."""
-    n = 1 << (len(pairs) - 1).bit_length()
-    return pairs + [pairs[-1]] * (n - len(pairs))
-
-
 class OffloadEngine:
     def __init__(self, model: Model, params, ecfg: EngineConfig):
         cfg = model.cfg
@@ -126,8 +138,10 @@ class OffloadEngine:
         self.params = params
         self.dtype = L._dt(cfg)
 
-        flat = unstack_layers(cfg, params)
-        self.layer_params = flat
+        # per-layer views for the decode loop, minus the experts: slicing a
+        # stacked leaf copies it, and the engine serves experts from its
+        # pools — a sliced copy would hold every expert on the device twice
+        self.layer_params = unstack_layers(cfg, _without_experts(params))
         self.moe_layers = [i for i, m in enumerate(cfg.layer_is_moe()) if m]
         self.num_moe_layers = len(self.moe_layers)
 
@@ -136,23 +150,25 @@ class OffloadEngine:
         wi_cols = 2 * f if cfg.ffn_activation == "swiglu" else f
 
         # ---- host storage: hi (dense) + lo (quantized) versions ----
+        # built one expert at a time, sliced straight out of the (stacked)
+        # leaf, so the device never holds more than one extra expert, in
+        # its own dtype
+        refs = _expert_refs(cfg, params)
         self.storage_hi: List[Dict[str, np.ndarray]] = []
         self.storage_lo: List[Dict[str, QTensor]] = []
         self.routers: List[np.ndarray] = []
         for li in self.moe_layers:
-            ffn = flat[li]["ffn"]
-            wi = np.asarray(ffn["experts"]["wi"], np.float32)  # (E, D, wi_cols)
-            wo = np.asarray(ffn["experts"]["wo"], np.float32)  # (E, F, D)
-            self.storage_hi.append({"wi": wi, "wo": wo})
+            experts, lead = refs[li]
+            hi, lo = {}, {}
+            for name in ("wi", "wo"):
+                hi[name], lo[name] = self._host_copies(experts[name], lead,
+                                                       e, ecfg)
             # host storage lives in numpy so background staging threads never
             # issue device computations
-            self.storage_lo.append({
-                "wi": _np_qtensor(quantize(jnp.asarray(wi), bits=ecfg.lo_bits,
-                                           group_size=ecfg.group_size)),
-                "wo": _np_qtensor(quantize(jnp.asarray(wo), bits=ecfg.lo_bits,
-                                           group_size=ecfg.group_size)),
-            })
-            self.routers.append(np.asarray(ffn["router"], np.float32))
+            self.storage_hi.append(hi)
+            self.storage_lo.append(lo)
+            self.routers.append(np.asarray(
+                self.layer_params[li]["ffn"]["router"], np.float32))
         # routers pre-stacked on device for the grouped (B,D)@(D,E) gating
         self.routers_dev = jnp.asarray(np.stack(self.routers))   # (L, D, E)
 
@@ -189,7 +205,7 @@ class OffloadEngine:
             else Thresholds(1.0, 1.0),
             self._fetch, lambda prec: self.expert_bytes[prec])
         link_bps = (ecfg.link_gbps * 1e9 if ecfg.link_gbps
-                    else measure_link_bps())
+                    else measure_link_bps(hi_b))
         self.scheduler = StagingEngine(
             self.loader, self._stage, self._commit_staged,
             streams=ecfg.streams, ordered=ecfg.ordered, link_bps=link_bps,
@@ -215,6 +231,25 @@ class OffloadEngine:
         self._admission = None          # ChunkedPrefill when ecfg.paged_kv
         self._pending_joins = {}        # dense-path incremental admissions
         self._unclaimed_joins = {}      # finished during a blocking join()
+
+    @staticmethod
+    def _host_copies(leaf, lead: Tuple[int, ...], num_experts: int,
+                     ecfg: EngineConfig):
+        """(hi float32, lo QTensor) host copies of one expert matrix stack:
+        expert j is ``leaf[(*lead, j)]``, sliced, quantized and fetched
+        alone."""
+        hi, data, scale, q = None, [], [], None
+        for j in range(num_experts):
+            w = leaf[(*lead, j)]
+            if hi is None:
+                hi = np.empty((num_experts, *w.shape), np.float32)
+            hi[j] = np.asarray(w, np.float32)
+            q = quantize(w, bits=ecfg.lo_bits, group_size=ecfg.group_size)
+            data.append(np.asarray(q.data))
+            scale.append(np.asarray(q.scale))
+        return hi, QTensor(data=np.stack(data), scale=np.stack(scale),
+                           bits=q.bits, group_size=q.group_size,
+                           orig_k=q.orig_k)
 
     # ------------------------------------------------------------------
     # device transfer
@@ -254,8 +289,8 @@ class OffloadEngine:
 
     def _scatter_fn(self, n_tensors: int):
         """Jitted multi-tensor slot scatter (eager `.at[].set` pays ~ms of
-        python dispatch per call on CPU; the jitted version is the single
-        fused update the issue's `_fetch_many` contract asks for)."""
+        python dispatch per call on CPU; the jitted version is one fused
+        update of every pool tensor)."""
         key = ("scatter", n_tensors)
         if key not in self._jit_cache:
             def scatter(pools, idx, values):
@@ -268,28 +303,22 @@ class OffloadEngine:
         return self._jit_cache[key]
 
     def _commit_staged(self, entries):
-        """Write staged buffers into the device pools: ONE `.at[idx].set`
-        scatter per pool tensor regardless of how many experts landed.
+        """Write staged buffers into the device pools, one expert per
+        scatter call: the scatter's shapes never depend on how many copies
+        landed together, so steady decode compiles no new variant, and only
+        one expert's bytes are in flight on the device at a time.
         entries: [(task_like_with_precision, slot, staged_dict)]."""
-        hi = [(s, buf) for t, s, buf in entries if t.precision == PREC_HI]
-        lo = [(s, buf) for t, s, buf in entries if t.precision != PREC_HI]
-        hi = pad_pow2(hi) if hi else hi
-        lo = pad_pow2(lo) if lo else lo
-        if hi:
-            idx = jnp.asarray([s for s, _ in hi], jnp.int32)
-            new = self._scatter_fn(2)(
-                [self.pool_hi["wi"], self.pool_hi["wo"]], idx,
-                [jnp.asarray(np.stack([b["wi"] for _, b in hi])),
-                 jnp.asarray(np.stack([b["wo"] for _, b in hi]))])
-            self.pool_hi["wi"], self.pool_hi["wo"] = new
-        if lo:
-            idx = jnp.asarray([s for s, _ in lo], jnp.int32)
-            names = ("wi_data", "wi_scale", "wo_data", "wo_scale")
-            new = self._scatter_fn(4)(
-                [self.pool_lo[n] for n in names], idx,
-                [jnp.asarray(np.stack([b[n] for _, b in lo])) for n in names])
+        for t, slot, buf in entries:
+            if t.precision == PREC_HI:
+                pool, names = self.pool_hi, ("wi", "wo")
+            else:
+                pool, names = self.pool_lo, ("wi_data", "wi_scale",
+                                             "wo_data", "wo_scale")
+            new = self._scatter_fn(len(names))(
+                [pool[n] for n in names], jnp.asarray([slot], jnp.int32),
+                [jnp.asarray(buf[n][None]) for n in names])
             for n, v in zip(names, new):
-                self.pool_lo[n] = v
+                pool[n] = v
 
     def _overflow_buffers(self, pp: int) -> Dict[str, np.ndarray]:
         """Reusable host staging buffers for union-overflow experts (cache
@@ -312,7 +341,7 @@ class OffloadEngine:
 
     def _fetch_many(self, items: List[Tuple[int, int, int, int]]):
         """Blocking batched fetch into admitted pool slots: items =
-        [(moe_idx, expert, precision, slot)], one scatter per pool tensor.
+        [(moe_idx, expert, precision, slot)], one scatter per expert.
         The decode hot paths go through `_stage`/`_commit_staged` directly
         (async prefetch, batched on-demand drain, overflow staging); this is
         the standalone batched-fetch entry point for warmup/pre-population

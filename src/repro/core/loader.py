@@ -85,6 +85,7 @@ from collections import deque
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Callable, Deque, List, Optional, Set, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.cache import CacheStarvation, MultidimensionalCache
@@ -94,21 +95,32 @@ from repro.core.scoring import (PREC_HI, PREC_LO, PREC_SKIP, Thresholds,
 ON_DEMAND, PREFETCH, UPGRADE = "on_demand", "prefetch", "upgrade"
 
 
-def measure_link_bps(nbytes: int = 1 << 22, repeats: int = 3) -> float:
-    """Measure the host-side copy bandwidth (bytes/s) used as the modeled
-    H2D link rate when `EngineConfig.link_gbps` is not set.
+def measure_link_bps(expert_bytes: int = 1 << 22, repeats: int = 3) -> float:
+    """Measure the host->device copy bandwidth (bytes/s) used as the
+    modeled link rate when `EngineConfig.link_gbps` is not set.
 
-    On this CPU-only container the "link" is a memcpy; on a real GPU host
-    this would be a pinned-memory H2D timing loop.  The result only feeds
-    the staging engine's issue-time budget accounting, never a sleep."""
-    src = np.ones(nbytes, np.uint8)
-    dst = np.empty_like(src)
+    On an accelerator backend this times a real
+    ``jax.device_put(...).block_until_ready()`` of an ``expert_bytes``
+    buffer; on the CPU backend, where host and device share memory, the
+    link is a 4 MiB host memcpy.  The result only feeds the staging
+    engine's issue-time budget accounting, never a sleep."""
+    if jax.default_backend() == "cpu":
+        src = np.ones(1 << 22, np.uint8)
+        dst = np.empty_like(src)
+
+        def copy():
+            np.copyto(dst, src)
+    else:
+        src = np.ones(expert_bytes, np.uint8)
+
+        def copy():
+            jax.device_put(src).block_until_ready()
     best = float("inf")
     for _ in range(repeats):
         t0 = time.perf_counter()
-        np.copyto(dst, src)
+        copy()
         best = min(best, time.perf_counter() - t0)
-    return nbytes / max(best, 1e-9)
+    return src.nbytes / max(best, 1e-9)
 
 
 @dataclasses.dataclass
@@ -268,7 +280,7 @@ class StagingEngine:
     ``downgraded`` for the engine's compute path) — in-flight copies are
     never interrupted.  With ``emulate_link=True`` each staged copy also
     *occupies* the modeled link for bytes/link_bps seconds, so wall-clock
-    stall numbers on this CPU-only container reflect link contention the
+    stall numbers reflect the modeled link's contention on any backend, the
     way the simulator's timeline does.
 
     Cache metadata is only ever touched on the main thread: prefetch

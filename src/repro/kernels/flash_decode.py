@@ -97,41 +97,53 @@ def flash_decode_pallas(q, k, v, lengths, *, block_s: int = 256,
 
 
 def _paged_flash_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
-                               o_ref, m_ref, l_ref, *, page_size: int,
+                               o_ref, m_sc, l_sc, acc_sc, *, page_size: int,
                                scale: float, softcap: float):
     bb = pl.program_id(0)
-    pp = pl.program_id(2)
+    pp = pl.program_id(1)
 
     @pl.when(pp == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    q = q_ref[0, 0, :].astype(jnp.float32)               # (hd,)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # (psz, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)            # (psz, hd)
+    # GQA as a batch over kv heads: the g query heads of kv head h score
+    # only that head's page rows
+    q = q_ref[0].astype(jnp.float32)                          # (Hkv, g, hd)
+    k = jnp.swapaxes(k_ref[0].astype(jnp.float32), 0, 1)      # (Hkv, psz, hd)
+    v = jnp.swapaxes(v_ref[0].astype(jnp.float32), 0, 1)
     length = len_ref[bb]
 
-    logits = (k @ q) * scale                              # (psz,)
+    logits = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * scale           # (Hkv, g, psz)
     if softcap and softcap > 0:
         logits = jnp.tanh(logits / softcap) * softcap
     # token t of a slot lives at LOGICAL page t // psz: mask by the logical
     # page index pp, not the physical page the table maps it to
-    pos = pp * page_size + jax.lax.iota(jnp.int32, page_size)
-    logits = jnp.where(pos < length, logits, NEG_INF)
+    pos = pp * page_size + jax.lax.broadcasted_iota(jnp.int32,
+                                                    logits.shape, 2)
+    valid = pos < length
+    logits = jnp.where(valid, logits, NEG_INF)
 
-    m_old = m_ref[0, 0, 0]
-    m_new = jnp.maximum(m_old, jnp.max(logits))
+    m_old = m_sc[...]                                         # (Hkv, g, 1)
+    m_new = jnp.maximum(m_old, jnp.max(logits, axis=2, keepdims=True))
     p = jnp.exp(logits - m_new)
     # explicit re-mask: when EVERY position seen so far is invalid (length
     # 0), m_new == NEG_INF and exp(NEG_INF - NEG_INF) would turn the mask
-    # into uniform weights; zeroed p keeps l at 0 so the wrapper returns 0
-    p = jnp.where(pos < length, p, 0.0)
+    # into uniform weights; zeroed p keeps l at 0 so the output is 0
+    p = jnp.where(valid, p, 0.0)
     corr = jnp.exp(m_old - m_new)
-    l_ref[0, 0, 0] = l_ref[0, 0, 0] * corr + jnp.sum(p)
-    o_ref[0, 0, :] = o_ref[0, 0, :] * corr + p @ v
-    m_ref[0, 0, 0] = m_new
+    l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=2, keepdims=True)
+    acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
+        p, v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)                   # (Hkv, g, hd)
+    m_sc[...] = m_new
+
+    @pl.when(pp == pl.num_programs(1) - 1)
+    def _flush():
+        o_ref[0] = acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
 
 
 @functools.partial(jax.jit,
@@ -142,7 +154,7 @@ def paged_flash_decode_pallas(q, pages_k, pages_v, table, lengths, *,
                               softcap: float = 0.0):
     """Flash decode straight out of a paged KV pool: the page table rides in
     as a scalar-prefetch operand and drives the K/V block index maps, so each
-    grid step DMAs exactly one physical (psz, hd) page — the dense
+    grid step DMAs exactly one physical (psz, Hkv, hd) page — the dense
     (B, maxp*psz) gathered cache view is never materialized.
 
         q        (B, Hq, hd)           current-token queries
@@ -150,9 +162,11 @@ def paged_flash_decode_pallas(q, pages_k, pages_v, table, lengths, *,
         table    (B, maxp) int         physical page id per logical page
         lengths  (B,) int              #valid cache tokens per slot
 
-    GQA is handled in the index map (query head hh reads kv head hh // g) —
-    no repeated K/V is ever built.  Returns (B, Hq, hd) f32; rows with
-    length 0 return exact zeros."""
+    Grid (B, maxp): one step reads a whole page for all kv heads and scores
+    it against the query heads grouped by kv head, (Hkv, g, hd) against
+    (Hkv, psz, hd), so no repeated K/V is ever built.  The running max /
+    denominator / accumulator live in VMEM scratch across the page axis.
+    Returns (B, Hq, hd) f32; rows with length 0 return exact zeros."""
     b, hq, hd = q.shape
     num_pages, psz, hkv, hd2 = pages_k.shape
     assert hd2 == hd and pages_v.shape == pages_k.shape
@@ -164,30 +178,24 @@ def paged_flash_decode_pallas(q, pages_k, pages_v, table, lengths, *,
 
     kernel = functools.partial(_paged_flash_decode_kernel, page_size=psz,
                                scale=scale, softcap=softcap)
-    kv_spec = pl.BlockSpec(
-        (1, psz, 1, hd), lambda bb, hh, pp, tab, ln: (tab[bb, pp], 0, hh // g, 0))
+    kv_spec = pl.BlockSpec((1, psz, hkv, hd),
+                           lambda bb, pp, tab, ln: (tab[bb, pp], 0, 0, 0))
+    q_spec = pl.BlockSpec((1, hkv, g, hd),
+                          lambda bb, pp, tab, ln: (bb, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hq, maxp),
-        in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda bb, hh, pp, tab, ln: (bb, hh, 0)),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, hd), lambda bb, hh, pp, tab, ln: (bb, hh, 0)),
-            pl.BlockSpec((1, 1, 1), lambda bb, hh, pp, tab, ln: (bb, hh, 0)),
-            pl.BlockSpec((1, 1, 1), lambda bb, hh, pp, tab, ln: (bb, hh, 0)),
-        ],
+        grid=(b, maxp),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((hkv, g, 1), jnp.float32),
+                        pltpu.VMEM((hkv, g, 1), jnp.float32),
+                        pltpu.VMEM((hkv, g, hd), jnp.float32)],
     )
-    o, m, l = pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, 1), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), jnp.float32),
         interpret=interpret,
-    )(table.astype(jnp.int32), lengths.astype(jnp.int32), q, pages_k, pages_v)
-    return o / jnp.maximum(l, 1e-30)
+    )(table.astype(jnp.int32), lengths.astype(jnp.int32),
+      q.reshape(b, hkv, g, hd), pages_k, pages_v)
+    return out.reshape(b, hq, hd)

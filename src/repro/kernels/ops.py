@@ -24,6 +24,7 @@ graph (steady-state calls replay the trace without re-counting).
 
 from __future__ import annotations
 
+import math
 import os
 from typing import Dict
 
@@ -85,17 +86,18 @@ def _pad_to(v: int, m: int) -> int:
 
 def _k_block(k: int, group_size: int, pack: int, cap: int) -> int:
     """Largest k-block <= cap that divides K and covers whole quant groups
-    and packed bytes (K is a multiple of both by the quant layout)."""
+    and packed bytes (K is a multiple of both by the quant layout).  Blocks
+    whose packed width (bk / pack) is a multiple of the 128-lane tile are
+    preferred: the compiled grouped kernels need them."""
     # smallest legal tile: lcm(group_size, pack); group_size is a multiple
     # of pack for every supported layout, so group_size itself is legal
     step = group_size if group_size % pack == 0 else group_size * pack
-    best = step
-    m = step
-    while m <= min(cap, k):
-        if k % m == 0:
-            best = m
-        m += step
-    return best
+    lane_step = math.lcm(step, 128 * pack)
+    for s in (lane_step, step):
+        fits = [m for m in range(s, min(cap, k) + 1, s) if k % m == 0]
+        if fits:
+            return fits[-1]
+    return step
 
 
 def dequant_matmul(x, q: QTensor, *, mode: str = "auto",

@@ -48,7 +48,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     t0 = time.time()
     try:
         step_fn, args, in_sh, donate = input_specs(cfg, shape_name, mesh)
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(step_fn, in_shardings=in_sh,
                              donate_argnums=donate)
             lowered = jitted.lower(*args)

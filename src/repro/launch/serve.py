@@ -28,6 +28,7 @@ import numpy as np
 from repro.configs import get_config, smoke_variant
 from repro.core import EngineConfig, OffloadEngine, Thresholds
 from repro.core.simulator import HARDWARE, HobbitSimConfig, simulate_systems
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.quant.quantize import expert_nbytes
 from repro.serving.api import BackendConfig, generate, make_backend
@@ -138,14 +139,15 @@ def main():
                          "per-token downgrade semantics")
     ap.add_argument("--link-gbps", type=float, default=None,
                     help="modeled H2D link bandwidth in GB/s; default "
-                         "measures the host copy rate at startup.  An "
-                         "explicit value also *emulates* the link (copies "
-                         "occupy their stream for bytes/link seconds) so "
-                         "contended-link behavior is observable on this "
-                         "CPU-only host")
+                         "measures the backend's host->device copy rate at "
+                         "startup.  An explicit value also *emulates* the "
+                         "link (copies occupy their stream for bytes/link "
+                         "seconds) so contended-link behavior is "
+                         "reproducible on any backend")
     ap.add_argument("--hw", choices=list(HARDWARE), default="rtx4090",
                     help="hardware cost model for the simulated latency report")
     args = ap.parse_args()
+    enable_compile_cache()
 
     kind = args.backend or {"resident": "dense", "hobbit": "hobbit",
                             None: "dense"}[args.mode]

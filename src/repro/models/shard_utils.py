@@ -1,8 +1,8 @@
 """Mesh-aware internal sharding constraints.
 
 `constrain(x, ...dims)` applies jax.lax.with_sharding_constraint when traced
-under a mesh (the `with mesh:` context) that defines the named axes, and is
-a no-op otherwise (so model code runs unchanged in single-device tests).
+under a mesh (the `with jax.set_mesh(mesh):` context) that defines the named
+axes, and is a no-op otherwise (so model code runs unchanged in single-device tests).
 Dim tokens:
 
     "batch"  -> all data-parallel axes present (("pod","data") or ("data",))
@@ -17,21 +17,10 @@ from jax.sharding import PartitionSpec as P
 
 
 def _active_mesh():
-    """The mesh in scope during tracing, or None."""
-    try:
-        from jax._src import mesh as mesh_lib
-        pm = mesh_lib.thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm
-    except Exception:
-        pass
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and am.axis_names:
-            return am
-    except Exception:
-        pass
-    return None
+    """The mesh in scope during tracing (set with ``jax.set_mesh``), or
+    None."""
+    am = jax.sharding.get_abstract_mesh()
+    return am if am.axis_names else None
 
 
 def axis_size(name: str) -> int:

@@ -136,8 +136,8 @@ def test_async_prefetch_matches_sync_and_reports_overlap(setup):
 
 
 def test_fetch_many_writes_all_slots(setup):
-    """Batched fetch: every admitted slot is written through one scatter per
-    pool tensor and counted as loader traffic."""
+    """Batched fetch: every admitted slot is written through the commit
+    scatter and counted as loader traffic."""
     m, params = setup
     eng = OffloadEngine(m, params, EngineConfig(hi_slots=8, lo_slots=4))
     eng.start_batch(1, 8)

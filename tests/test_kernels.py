@@ -211,7 +211,7 @@ def _mk_paged(b, hq, hkv, hd, psz, maxp, num_pages, dtype=jnp.float32, seed=0):
 @pytest.mark.parametrize("psz,maxp", [(4, 6), (8, 3)])
 def test_paged_flash_decode_kernel_vs_oracle(hq, hkv, psz, maxp):
     """Table-driven kernel == gather + masked-softmax oracle, incl. GQA
-    (kernel reads kv head hh // g through the index map, never repeats)."""
+    (query heads grouped per kv head in-kernel, K/V never repeated)."""
     q, pk, pv, table = _mk_paged(3, hq, hkv, 32, psz, maxp, 12,
                                  seed=hq * 10 + psz)
     rng = np.random.default_rng(1)
@@ -342,6 +342,37 @@ def test_grouped_dequant_combine_pads_and_unvisited_rows():
         np.testing.assert_array_equal(np.asarray(got[r]), 0.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("bits", [8, 4, 2])
+@pytest.mark.parametrize("n", [640, 1024])
+@pytest.mark.parametrize("kernel", ["matmul", "combine"])
+def test_grouped_dequant_n_blocks_vs_oracle(kernel, n, bits):
+    """Real-width tiling: N wider than one 512-lane block (640 -> five
+    128-lane blocks, 1024 -> two 512-lane blocks) and two k-blocks whose
+    packed width is a multiple of 128, so the N-block index maps, the
+    combine's revisits within each N block and its re-initialisation
+    when the N block changes all run; the combine also carries pads and
+    an unvisited row."""
+    b, k_ = 4, 1024
+    x, data, scale = _mk_grouped(6, k_, n, bits, 64, seed=n + bits)
+    kw = dict(bits=bits, group_size=64)
+    if kernel == "matmul":
+        got = grouped_dequant_matmul_pallas(x, data, scale, block_k=512,
+                                            interpret=True, **kw)
+        want = ops.grouped_dequant_matmul(x, data, scale, mode="xla", **kw)
+    else:
+        # rows 0, 1 and 3 visited, row 2 unvisited; two pads at the end
+        rows = jnp.asarray([0, 0, 1, 3, b, b], jnp.int32)
+        wts = jnp.asarray([0.7, 0.3, 1.0, 0.5, 0.0, 0.0], jnp.float32)
+        got = grouped_dequant_combine_pallas(x, data, scale, rows, wts,
+                                             num_rows=b, block_k=512,
+                                             interpret=True, **kw)
+        want = ref.grouped_dequant_combine_ref(x, data, scale, rows, wts,
+                                               num_rows=b, **kw)
+        np.testing.assert_array_equal(np.asarray(got[2]), 0.0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-3)
 
 
 def test_grouped_dequant_combine_ops_wrapper_matches_ref():

@@ -51,7 +51,7 @@ _SCRIPT = textwrap.dedent("""
                       nu=sh.param_shardings(mesh, state.opt.nu))
     b_sh = Batch(tokens=NamedSharding(mesh, P("data", None)),
                  loss_mask=NamedSharding(mesh, P("data", None)))
-    with mesh:
+    with jax.set_mesh(mesh):
         f = jax.jit(step, in_shardings=(TrainState(p_sh, opt_sh), b_sh))
         s_sp, m_sp = f(state, batch)
     loss_sp = float(m_sp["loss"])

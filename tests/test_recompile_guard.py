@@ -1,9 +1,10 @@
 """Steady-state jit recompilation guards over the decode hot path.
 
-The decode loop's performance story (fixed-P pow2-padded scatters, static
-page tables, donated pools) collapses if any step retraces: one silent
-recompile costs more than a hundred steps.  These tests warm a backend up,
-snapshot every jitted callable's compiled-variant count (`_cache_size()`),
+The decode loop's performance story (fixed-P pair grids, one-expert
+commit scatters, static page tables, donated pools) collapses if any step
+retraces: one silent recompile costs more than a hundred steps.  These
+tests warm a backend up, snapshot every jitted callable's compiled-variant
+count (`_cache_size()`),
 run more decode steps at identical shapes, and require the counts to be
 bit-identical — for the grouped, paged-KV and multi-stream-staged engine
 configurations plus the paged dense backend.

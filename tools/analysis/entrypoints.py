@@ -134,28 +134,6 @@ def _smoke() -> Tuple[Any, Any, Any]:
     return m, params, eng
 
 
-def _scatter_builds(pools: Any, values_shape: Any,
-                    dtypes: Any) -> Tuple[Tuple[Any, ...], ...]:
-    """Variant-budget shape set for the commit scatter: staged counts 1..8
-    padded with the engine's own `pad_pow2`, so the set compiles exactly
-    log2(pool) signatures — the static twin of the runtime recompile guard.
-    Removing the production padding changes these builds and blows the
-    declared budget."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core.engine import pad_pow2
-
-    S = jax.ShapeDtypeStruct
-    builds = []
-    for staged in range(1, 9):
-        n = len(pad_pow2(list(range(staged))))
-        idx = S((n,), jnp.int32)
-        values = [S((n, *shape), dt) for shape, dt in zip(values_shape, dtypes)]
-        builds.append((pools, idx, values))
-    return tuple(builds)
-
-
 def build_registry() -> Tuple[List[EntryPoint], List[Tuple[str, str, str]]]:
     """Build the hot-path registry against the live tree.
 
@@ -281,7 +259,7 @@ def build_registry() -> Tuple[List[EntryPoint], List[Tuple[str, str, str]]]:
                   donate=(2, 3), pool_args=(2, 3),
                   dense_shapes=(dense,)))
 
-        # ---- StagingEngine's batched commit scatter (hi / lo pools) ----
+        # ---- StagingEngine's per-expert commit scatter (hi / lo pools) ----
         # The traced fns are the PRODUCTION jitted objects out of
         # eng._scatter_fn's cache — donation included.  The scatter is pure
         # index math with no kernel dispatch, so one mode suffices.
@@ -293,12 +271,10 @@ def build_registry() -> Tuple[List[EntryPoint], List[Tuple[str, str, str]]]:
                   name="engine.commit_scatter_hi",
                   target="repro.core.engine:OffloadEngine._scatter_fn",
                   fn=eng._scatter_fn(2),
-                  args=(hi_pools, S((2,), jnp.int32),
-                        [S((2, *s), jnp.float32) for s in hi_shapes]),
+                  args=(hi_pools, S((1,), jnp.int32),
+                        [S((1, *s), jnp.float32) for s in hi_shapes]),
                   donate=(0,), pool_args=(0,),
-                  variant_builds=_scatter_builds(
-                      hi_pools, hi_shapes, [jnp.float32, jnp.float32]),
-                  variant_budget=4, modes=(XLA,)))
+                  modes=(XLA,)))
 
         lo_names = ("wi_data", "wi_scale", "wo_data", "wo_scale")
         lo_pools = [lo_pool[n] for n in lo_names]
@@ -310,11 +286,10 @@ def build_registry() -> Tuple[List[EntryPoint], List[Tuple[str, str, str]]]:
                   name="engine.commit_scatter_lo",
                   target="repro.core.engine:OffloadEngine._scatter_fn",
                   fn=eng._scatter_fn(4),
-                  args=(lo_pools, S((2,), jnp.int32),
-                        [S((2, *s), dt) for s, dt in zip(lo_shapes, lo_dts)]),
+                  args=(lo_pools, S((1,), jnp.int32),
+                        [S((1, *s), dt) for s, dt in zip(lo_shapes, lo_dts)]),
                   donate=(0,), pool_args=(0,),
-                  variant_builds=_scatter_builds(lo_pools, lo_shapes, lo_dts),
-                  variant_budget=4, modes=(XLA,)))
+                  modes=(XLA,)))
 
         # ---- paged decode / prefill-chunk jits (model + serving tier) ----
         kpages = [S(eng.kv_pool.k[i].shape, act)

@@ -117,7 +117,7 @@ def _subjaxprs(eqn: Any) -> List[Tuple[Any, bool]]:
     """(inner_jaxpr, entered_pallas) pairs reachable from one eqn's params —
     handles ClosedJaxpr params (pjit, scan, ...) and the raw Jaxpr that
     ``pallas_call`` carries, nested arbitrarily in lists/tuples."""
-    import jax
+    from jax.extend import core as jex_core
     is_pallas = eqn.primitive.name == "pallas_call"
     out: List[Tuple[Any, bool]] = []
     for val in eqn.params.values():
@@ -126,7 +126,7 @@ def _subjaxprs(eqn: Any) -> List[Tuple[Any, bool]]:
             inner = getattr(v, "jaxpr", None)
             if inner is not None:
                 out.append((inner, is_pallas))
-            elif isinstance(v, jax.core.Jaxpr):
+            elif isinstance(v, jex_core.Jaxpr):
                 out.append((v, is_pallas))
     return out
 
@@ -242,11 +242,11 @@ class _FlatGraph:
         self._walk(jaxpr.jaxpr, (), False)
 
     def _link(self, inner_vars: Any, outer_vars: Any) -> None:
-        import jax
+        from jax.extend import core as jex_core
         if len(inner_vars) != len(outer_vars):
             return
         for iv, ov in zip(inner_vars, outer_vars):
-            if isinstance(iv, jax.core.Var) and isinstance(ov, jax.core.Var):
+            if isinstance(iv, jex_core.Var) and isinstance(ov, jex_core.Var):
                 self.alias[id(iv)] = ov
 
     def canon(self, var: Any) -> Any:
