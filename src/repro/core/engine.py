@@ -34,11 +34,23 @@ from repro.core.predictor import AdaptiveExpertPredictor
 from repro.core.scoring import (PREC_HI, PREC_LO, PREC_SKIP, Thresholds,
                                 precision_decisions)
 from repro.core.simulator import TraceLayer
+from repro.core.spans import Spans
 from repro.kernels import ops as kops
 from repro.models import layers as L
 from repro.models import unstack_layers
 from repro.models.model import Batch, Model
 from repro.quant.quantize import QTensor, dequantize, expert_nbytes, quantize
+
+# the engine's host spans (core/spans.py, totals in stats()["spans"]): the
+# decode step's root, its direct children (each hobbit.layer.* span opens
+# once per MoE layer), an expert commit (inside stage_wait during decode)
+# and one prefill chunk
+SPANS = ("hobbit.decode", "hobbit.decode.prologue", "hobbit.layer.attn",
+         "hobbit.layer.gate", "hobbit.layer.route", "hobbit.layer.prefetch",
+         "hobbit.layer.stage_wait", "hobbit.layer.plan",
+         "hobbit.layer.ship_overflow", "hobbit.layer.ffn",
+         "hobbit.decode.logits", "hobbit.stage.commit",
+         "hobbit.prefill.chunk")
 
 
 def _without_experts(params):
@@ -137,6 +149,14 @@ class OffloadEngine:
         self.ecfg = ecfg
         self.params = params
         self.dtype = L._dt(cfg)
+        # owner: main-thread
+        self.spans = Spans(SPANS)
+        self._steps = 0                 # decode steps (the root span's step)
+        self._host_syncs = 0            # device->host reads in decode
+        self._overflow_shipped_bytes = 0
+        self._commit_shipped_bytes = 0
+        # routed computations by the precision actually computed
+        self._served = {PREC_HI: 0, PREC_LO: 0, PREC_SKIP: 0}
 
         # per-layer views for the decode loop, minus the experts: slicing a
         # stacked leaf copies it, and the engine serves experts from its
@@ -217,7 +237,6 @@ class OffloadEngine:
         self._pending_preds: List = []
         self.trace: List[List[TraceLayer]] = []
         self._jit_cache: Dict[str, callable] = {}
-        self._gating_s = 0.0
         self._expert_dispatches = 0     # grouped-path compute dispatches
         self._union_reloads = 0         # same-layer contention re-fetches
         self._layer_s_ema = 0.0         # per-layer compute EMA (deadline hints)
@@ -293,13 +312,14 @@ class OffloadEngine:
         update of every pool tensor)."""
         key = ("scatter", n_tensors)
         if key not in self._jit_cache:
-            def scatter(pools, idx, values):
+            def hobbit_commit_scatter(pools, idx, values):
                 return [p.at[idx].set(v.astype(p.dtype))
                         for p, v in zip(pools, values)]
             # donate the pool buffers: callers rebind the pools to the
             # returned arrays immediately, so keeping the inputs alive would
             # hold two full copies of every expert pool per commit
-            self._jit_cache[key] = jax.jit(scatter, donate_argnums=0)
+            self._jit_cache[key] = jax.jit(hobbit_commit_scatter,
+                                           donate_argnums=0)
         return self._jit_cache[key]
 
     def _commit_staged(self, entries):
@@ -308,17 +328,26 @@ class OffloadEngine:
         landed together, so steady decode compiles no new variant, and only
         one expert's bytes are in flight on the device at a time.
         entries: [(task_like_with_precision, slot, staged_dict)]."""
-        for t, slot, buf in entries:
-            if t.precision == PREC_HI:
-                pool, names = self.pool_hi, ("wi", "wo")
-            else:
-                pool, names = self.pool_lo, ("wi_data", "wi_scale",
-                                             "wo_data", "wo_scale")
-            new = self._scatter_fn(len(names))(
-                [pool[n] for n in names], jnp.asarray([slot], jnp.int32),
-                [jnp.asarray(buf[n][None]) for n in names])
-            for n, v in zip(names, new):
-                pool[n] = v
+        with self.spans("hobbit.stage.commit"):
+            for t, slot, buf in entries:
+                if t.precision == PREC_HI:
+                    pool, names = self.pool_hi, ("wi", "wo")
+                else:
+                    pool, names = self.pool_lo, ("wi_data", "wi_scale",
+                                                 "wo_data", "wo_scale")
+                host = [buf[n][None] for n in names]
+                self._commit_shipped_bytes += sum(a.nbytes for a in host)
+                new = self._scatter_fn(len(names))(
+                    [pool[n] for n in names], jnp.asarray([slot], jnp.int32),
+                    [jnp.asarray(a) for a in host])
+                for n, v in zip(names, new):
+                    pool[n] = v
+
+    def _to_host(self, x, dtype=None) -> np.ndarray:
+        """Read a device array to the host.  Every device->host read of a
+        decode step goes through here and counts in ``host_syncs``."""
+        self._host_syncs += 1
+        return np.asarray(x, dtype)
 
     def _overflow_buffers(self, pp: int) -> Dict[str, np.ndarray]:
         """Reusable host staging buffers for union-overflow experts (cache
@@ -393,7 +422,7 @@ class OffloadEngine:
         written (copying shared pages off their sharers first — decode
         appending into an aliased prefix page must not corrupt it) and
         export the page table once per step."""
-        pos = np.asarray(self.positions)
+        pos = self._to_host(self.positions)
         for r in rows:
             p = int(pos[r])
             self.kv_pool.ensure(r, p + 1)
@@ -491,8 +520,13 @@ class OffloadEngine:
         return y[:, None, :]                                # (B, 1, D)
 
     def _jit(self, name, fn, donate=()):
+        """``fn`` jitted once per engine as the program ``hobbit_<name>``
+        (``jit_hobbit_<name>`` in a profiler trace)."""
         if name not in self._jit_cache:
-            self._jit_cache[name] = jax.jit(fn, donate_argnums=donate)
+            def program(*args):
+                return fn(*args)
+            program.__name__ = program.__qualname__ = f"hobbit_{name}"
+            self._jit_cache[name] = jax.jit(program, donate_argnums=donate)
         return self._jit_cache[name]
 
     # ------------------------------------------------------------------
@@ -633,21 +667,22 @@ class OffloadEngine:
         jitted call under paged KV); completed slots become active.  Returns
         {slot: last-token logits} — including logits another slot's blocking
         ``join`` finished but did not claim."""
-        done: Dict[int, np.ndarray] = dict(self._unclaimed_joins)
-        self._unclaimed_joins = {}
-        if self.ecfg.paged_kv:
-            done.update(self._admission.step())
-            for slot in done:
-                plen = int(self.kv_pool.lens[slot])
-                self.positions = self.positions.at[slot].set(plen)
-                self.active[slot] = True
-                self._pending_preds = [pp for pp in self._pending_preds
-                                       if pp[2] != slot]
+        with self.spans("hobbit.prefill.chunk"):
+            done: Dict[int, np.ndarray] = dict(self._unclaimed_joins)
+            self._unclaimed_joins = {}
+            if self.ecfg.paged_kv:
+                done.update(self._admission.step())
+                for slot in done:
+                    plen = int(self.kv_pool.lens[slot])
+                    self.positions = self.positions.at[slot].set(plen)
+                    self.active[slot] = True
+                    self._pending_preds = [pp for pp in self._pending_preds
+                                           if pp[2] != slot]
+                return done
+            for slot, prompt in list(self._pending_joins.items()):
+                del self._pending_joins[slot]
+                done[slot] = self._join_dense(slot, prompt)
             return done
-        for slot, prompt in list(self._pending_joins.items()):
-            del self._pending_joins[slot]
-            done[slot] = self._join_dense(slot, prompt)
-        return done
 
     def can_admit(self, tokens: int, *, prompt=None) -> bool:
         """KV-capacity admission gate: paged KV checks unreserved pages
@@ -724,9 +759,11 @@ class OffloadEngine:
         reference path (``grouped=False`` or host compute mode), kept as the
         numerics baseline the parity tests compare against."""
         self._check_open()
-        if self.ecfg.grouped and self.ecfg.compute_mode == "device":
-            return self._decode_step_batch_grouped(tokens)
-        return self._decode_step_batch_reference(tokens)
+        self._steps += 1
+        with self.spans.step("hobbit.decode", self._steps):
+            if self.ecfg.grouped and self.ecfg.compute_mode == "device":
+                return self._decode_step_batch_grouped(tokens)
+            return self._decode_step_batch_reference(tokens)
 
     # ---- shared per-layer bookkeeping ----
     def _score_pending_preds(self, mi: int, tops: Dict[int, np.ndarray]):
@@ -810,6 +847,7 @@ class OffloadEngine:
     # ---- grouped implementation (the serving hot path) ----
     def _decode_step_batch_grouped(self, tokens) -> np.ndarray:
         cfg, ecfg, mc = self.cfg, self.ecfg, self.cfg.moe
+        sp = self.spans
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         assert tokens.shape[0] == self.batch, (tokens.shape, self.batch)
         b, k = self.batch, mc.top_k
@@ -831,179 +869,213 @@ class OffloadEngine:
 
         table = active_dev = None
         if ecfg.paged_kv:
-            table, active_dev = self._paged_step_prologue(rows)
+            with sp("hobbit.decode.prologue"):
+                table, active_dev = self._paged_step_prologue(rows)
         row_trace = {r: [] for r in rows}
         for mi, li in enumerate(self.moe_layers):
-            t_layer0 = time.perf_counter()
-            # deadline hint: the staging engine budgets queued copies against
-            # (target_layer - mi) * per-layer compute seconds of link time
-            self.scheduler.set_deadline_clock(mi, self._layer_s_ema,
-                                              self._layer_period_ema)
-            p = self.layer_params[li]
-            x = self._attn_layer(li, x, table=table, active_dev=active_dev)
-            h = ffn_in(p, x)                                   # (B,1,D)
+            with sp("hobbit.layer.attn", layer=mi):
+                t_layer0 = time.perf_counter()
+                # deadline hint: the staging engine budgets queued copies
+                # against (target_layer - mi) * per-layer compute seconds of
+                # link time
+                self.scheduler.set_deadline_clock(mi, self._layer_s_ema,
+                                                  self._layer_period_ema)
+                p = self.layer_params[li]
+                x = self._attn_layer(li, x, table=table, active_dev=active_dev)
+                h = ffn_in(p, x)                               # (B,1,D)
+                # forcing h here keeps the pending attn/ffn-in compute out
+                # of the gate span
+                h_host = self._to_host(h[:, 0], np.float32)    # (B,D)
 
             # ---- gating: ONE (B,D)@(D,E) matmul from the stacked routers --
-            h_host = np.asarray(h[:, 0], np.float32)           # (B,D)
-            # (forcing h above keeps the pending attn/ffn-in compute out of
-            # the gating timer)
-            tg0 = time.perf_counter()
-            _, vals_g, idx_g = gate_fn(h[:, 0], self.routers_dev[mi])
-            vals_np = np.asarray(vals_g[0], np.float32)        # (B,K)
-            idx_np = np.asarray(idx_g[0], np.int32)            # (B,K)
-            self._gating_s += time.perf_counter() - tg0
-            tops: Dict[int, np.ndarray] = {}
-            gates: Dict[int, np.ndarray] = {}
-            for r in rows:
-                tops[r] = idx_np[r]
-                gates[r] = vals_np[r]
-                for e, g in zip(tops[r], gates[r]):
-                    self.fleet.observe((mi, int(e)), float(g))
+            with sp("hobbit.layer.gate", layer=mi):
+                _, vals_g, idx_g = gate_fn(h[:, 0], self.routers_dev[mi])
+                vals_np = self._to_host(vals_g[0], np.float32)  # (B,K)
+                idx_np = self._to_host(idx_g[0], np.int32)      # (B,K)
 
-            self._score_pending_preds(mi, tops)
+            with sp("hobbit.layer.route", layer=mi):
+                tops: Dict[int, np.ndarray] = {}
+                gates: Dict[int, np.ndarray] = {}
+                for r in rows:
+                    tops[r] = idx_np[r]
+                    gates[r] = vals_np[r]
+                    for e, g in zip(tops[r], gates[r]):
+                        self.fleet.observe((mi, int(e)), float(g))
 
-            # ---- on-demand scoring (union over slots) ----
-            # Hard-pin this layer's experts BEFORE prefetch admission: async
-            # submit_prefetch admits (and may evict) at submit time, so
-            # without the pins it could evict a resident expert this very
-            # layer is about to compute with.
-            self.loader.new_layer()
-            for r in rows:
-                self.loader.score_and_enqueue(mi, tops[r].tolist(), gates[r],
-                                              clear_pins=False)
+                self._score_pending_preds(mi, tops)
 
-            pred_entry = {}
-            if ecfg.prefetch:
-                pred_entry = self._prefetch_predictions(
-                    mi, rows, h_host, use_async=ecfg.async_prefetch)
+                # ---- on-demand scoring (union over slots) ----
+                # Hard-pin this layer's experts BEFORE prefetch admission:
+                # async submit_prefetch admits (and may evict) at submit
+                # time, so without the pins it could evict a resident
+                # expert this very layer is about to compute with.
+                self.loader.new_layer()
+                for r in rows:
+                    self.loader.score_and_enqueue(mi, tops[r].tolist(),
+                                                  gates[r], clear_pins=False)
+
+            with sp("hobbit.layer.prefetch", layer=mi):
+                pred_entry = {}
+                if ecfg.prefetch:
+                    pred_entry = self._prefetch_predictions(
+                        mi, rows, h_host, use_async=ecfg.async_prefetch)
 
             # ---- loading ----
-            t_load0 = time.perf_counter()
-            if ecfg.async_prefetch:
-                # barrier: land every prefetch targeting this layer (copies
-                # have been staging in the background since they were
-                # predicted), then blocking-load the residual miss set in one
-                # batched transfer
-                self.scheduler.wait(mi)
-                self.scheduler.drain_on_demand(self.loader.take_queued(), mi)
-            else:
-                self.loader.drain(mi)
-            t_load = time.perf_counter() - t_load0
+            with sp("hobbit.layer.stage_wait", layer=mi):
+                t_load0 = time.perf_counter()
+                if ecfg.async_prefetch:
+                    # barrier: land every prefetch targeting this layer
+                    # (copies have been staging in the background since they
+                    # were predicted), then blocking-load the residual miss
+                    # set in one batched transfer
+                    self.scheduler.wait(mi)
+                    self.scheduler.drain_on_demand(self.loader.take_queued(),
+                                                   mi)
+                else:
+                    self.loader.drain(mi)
+                t_load = time.perf_counter() - t_load0
 
             # ---- grouped expert compute: 1 hi + 1 lo dispatch ----
-            # Union-overflow pairs (a same-layer neighbour's admission
-            # evicted this expert: union demand > pool) ride in per-layer
-            # overflow staging buffers appended after the pool slots instead
-            # of re-admitting — re-admission could evict a slot an earlier
-            # pair already claimed, corrupting its compute.  The re-fetch
-            # still counts as a miss + load so hit_ratio reflects real
-            # traffic under contention.
-            pp = b * k
-            hi_rows = np.full(pp, b, np.int32)
-            hi_ranks = np.zeros(pp, np.int32)
-            hi_slots = np.zeros(pp, np.int32)
-            lo_rows = np.full(pp, b, np.int32)
-            lo_ranks = np.zeros(pp, np.int32)
-            lo_slots = np.zeros(pp, np.int32)
-            w_hi = np.zeros((b, k), np.float32)
-            w_lo = np.zeros((b, k), np.float32)
-            ovf = self._overflow_buffers(pp)
-            n_hi = n_lo = 0
-            n_ovf_hi = n_ovf_lo = 0
+            with sp("hobbit.layer.plan", layer=mi):
+                plan, ovf = self._plan_layer(mi, rows, tops, gates)
+
+            with sp("hobbit.layer.ship_overflow", layer=mi):
+                # jnp.asarray with a dtype turns the float32 hi buffers to
+                # the model dtype on the host, before they cross
+                ovf_dev = (jnp.asarray(ovf["hi_wi"], self.dtype),
+                           jnp.asarray(ovf["hi_wo"], self.dtype),
+                           jnp.asarray(ovf["lo_wi_data"]),
+                           jnp.asarray(ovf["lo_wi_scale"]),
+                           jnp.asarray(ovf["lo_wo_data"]),
+                           jnp.asarray(ovf["lo_wo_scale"]))
+                self._overflow_shipped_bytes += sum(a.nbytes
+                                                    for a in ovf_dev)
+
+            with sp("hobbit.layer.ffn", layer=mi):
+                y = grouped_ffn(self.pool_hi["wi"], self.pool_hi["wo"],
+                                self.pool_lo["wi_data"],
+                                self.pool_lo["wi_scale"],
+                                self.pool_lo["wo_data"],
+                                self.pool_lo["wo_scale"], *ovf_dev, h,
+                                *(jnp.asarray(a) for a in plan))
+                # free the device copies now, as when they were arguments
+                # only: held into the next layer they would sit beside its
+                # own and raise peak memory by a layer's buffers
+                del ovf_dev
+                self._expert_dispatches += 1
+                x = combine_fn(x, y)
+
+                for r in rows:
+                    row_trace[r].append(self._trace_entry(mi, r, tops, gates,
+                                                          pred_entry))
+                # downgrade markers are per-token decisions: consumed this
+                # layer, never carried into later steps' precision choices
+                self.scheduler.retire_layer(mi)
+                # per-layer compute EMA (loading time excluded) — the
+                # staging engine's deadline clock budgets link bytes against
+                # it — and full-period EMA (loading included) — its per-pump
+                # stream feed
+                dt_full = time.perf_counter() - t_layer0
+                dt = dt_full - t_load
+                self._layer_s_ema = (dt if self._layer_s_ema == 0.0
+                                     else 0.8 * self._layer_s_ema + 0.2 * dt)
+                self._layer_period_ema = (
+                    dt_full if self._layer_period_ema == 0.0
+                    else 0.8 * self._layer_period_ema + 0.2 * dt_full)
+
+        with sp("hobbit.decode.logits"):
+            self.positions = self.positions + jnp.asarray(
+                self.active.astype(np.int32))
             for r in rows:
-                dec = precision_decisions(gates[r], self.loader.th)
-                for j in range(k):
-                    d_ = int(dec[j])
-                    if d_ == PREC_SKIP:
-                        continue
-                    e = int(tops[r][j])
-                    is_hi = d_ == PREC_HI
-                    slot = self.cache.lookup((mi, e), is_hi)
-                    if (slot is not None and is_hi
-                            and self.cache.is_inflight((mi, e), True)):
-                        # an upgrade re-copy owns the slot but its bytes are
-                        # still landing (wait() never blocks on upgrades);
-                        # the slot holds no hi weights yet
-                        slot = None
-                    if (slot is None and is_hi and ecfg.async_prefetch
-                            and self.scheduler.serves_lo_downgrade(mi, e)):
-                        # issue-time precision downgrade: the staging engine
-                        # replaced this hi copy with a lo one under link
-                        # pressure — compute from the lo pool until an
-                        # idle-link upgrade lands the hi copy
-                        is_hi = False
-                        slot = self.cache.lookup((mi, e), False)
-                        self.scheduler.served_lo_expert_steps += 1
-                    if slot is None:
-                        if is_hi:
-                            self.cache.stats.misses_hi += 1
-                        else:
-                            self.cache.stats.misses_lo += 1
-                        buf = self._stage(mi, e, d_)
-                        if is_hi:
-                            ovf["hi_wi"][n_ovf_hi] = buf["wi"]
-                            ovf["hi_wo"][n_ovf_hi] = buf["wo"]
-                            slot = self.ecfg.hi_slots + n_ovf_hi
-                            n_ovf_hi += 1
-                        else:
-                            for name in ("wi_data", "wi_scale", "wo_data",
-                                         "wo_scale"):
-                                ovf[f"lo_{name}"][n_ovf_lo] = buf[name]
-                            slot = self.ecfg.lo_slots + n_ovf_lo
-                            n_ovf_lo += 1
-                        self.loader.loaded_bytes += self.expert_bytes[d_]
-                        self.loader.n_loads[d_] += 1
-                        self._union_reloads += 1
-                    if is_hi:
-                        hi_rows[n_hi], hi_ranks[n_hi] = r, j
-                        hi_slots[n_hi] = slot
-                        w_hi[r, j] = gates[r][j]
-                        n_hi += 1
-                    else:
-                        lo_rows[n_lo], lo_ranks[n_lo] = r, j
-                        lo_slots[n_lo] = slot
-                        w_lo[r, j] = gates[r][j]
-                        n_lo += 1
+                self.trace.append(row_trace[r])
+            lg = self.model.logits(self.params, x)[:, 0]
+            return self._to_host(lg, np.float32)
 
-            y = grouped_ffn(self.pool_hi["wi"], self.pool_hi["wo"],
-                            self.pool_lo["wi_data"], self.pool_lo["wi_scale"],
-                            self.pool_lo["wo_data"], self.pool_lo["wo_scale"],
-                            jnp.asarray(ovf["hi_wi"], self.dtype),
-                            jnp.asarray(ovf["hi_wo"], self.dtype),
-                            jnp.asarray(ovf["lo_wi_data"]),
-                            jnp.asarray(ovf["lo_wi_scale"]),
-                            jnp.asarray(ovf["lo_wo_data"]),
-                            jnp.asarray(ovf["lo_wo_scale"]),
-                            h, jnp.asarray(hi_rows), jnp.asarray(hi_ranks),
-                            jnp.asarray(hi_slots), jnp.asarray(lo_rows),
-                            jnp.asarray(lo_ranks), jnp.asarray(lo_slots),
-                            jnp.asarray(w_hi), jnp.asarray(w_lo))
-            self._expert_dispatches += 1
-            x = combine_fn(x, y)
+    def _plan_layer(self, mi: int, rows, tops, gates):
+        """Place every routed (row, rank) of MoE layer mi in the hi GEMM, the
+        lo GEMM or neither, by the precision actually served, and count it
+        in ``served_*``.  Returns ``_grouped_ffn``'s index and weight arrays
+        (hi_rows, hi_ranks, hi_slots, lo_rows, lo_ranks, lo_slots, w_hi,
+        w_lo; fixed length P = batch * top_k) and the host overflow buffers.
 
-            for r in rows:
-                row_trace[r].append(self._trace_entry(mi, r, tops, gates,
-                                                      pred_entry))
-            # downgrade markers are per-token decisions: consumed this layer,
-            # never carried into later steps' precision choices
-            self.scheduler.retire_layer(mi)
-            # per-layer compute EMA (loading time excluded) — the staging
-            # engine's deadline clock budgets link bytes against it — and
-            # full-period EMA (loading included) — its per-pump stream feed
-            dt_full = time.perf_counter() - t_layer0
-            dt = dt_full - t_load
-            self._layer_s_ema = (dt if self._layer_s_ema == 0.0
-                                 else 0.8 * self._layer_s_ema + 0.2 * dt)
-            self._layer_period_ema = (
-                dt_full if self._layer_period_ema == 0.0
-                else 0.8 * self._layer_period_ema + 0.2 * dt_full)
-
-        self.positions = self.positions + jnp.asarray(
-            self.active.astype(np.int32))
+        Union-overflow pairs (a same-layer neighbour's admission evicted this
+        expert: union demand > pool) ride in per-layer overflow staging
+        buffers appended after the pool slots instead of re-admitting —
+        re-admission could evict a slot an earlier pair already claimed,
+        corrupting its compute.  The re-fetch still counts as a miss + load
+        so hit_ratio reflects real traffic under contention."""
+        ecfg = self.ecfg
+        b, k = self.batch, self.cfg.moe.top_k
+        pp = b * k
+        hi_rows = np.full(pp, b, np.int32)
+        hi_ranks = np.zeros(pp, np.int32)
+        hi_slots = np.zeros(pp, np.int32)
+        lo_rows = np.full(pp, b, np.int32)
+        lo_ranks = np.zeros(pp, np.int32)
+        lo_slots = np.zeros(pp, np.int32)
+        w_hi = np.zeros((b, k), np.float32)
+        w_lo = np.zeros((b, k), np.float32)
+        ovf = self._overflow_buffers(pp)
+        n_hi = n_lo = 0
+        n_ovf_hi = n_ovf_lo = 0
         for r in rows:
-            self.trace.append(row_trace[r])
-        lg = self.model.logits(self.params, x)[:, 0]
-        return np.asarray(lg, np.float32)
+            dec = precision_decisions(gates[r], self.loader.th)
+            for j in range(k):
+                d_ = int(dec[j])
+                if d_ == PREC_SKIP:
+                    self._served[PREC_SKIP] += 1
+                    continue
+                e = int(tops[r][j])
+                is_hi = d_ == PREC_HI
+                slot = self.cache.lookup((mi, e), is_hi)
+                if (slot is not None and is_hi
+                        and self.cache.is_inflight((mi, e), True)):
+                    # an upgrade re-copy owns the slot but its bytes are
+                    # still landing (wait() never blocks on upgrades); the
+                    # slot holds no hi weights yet
+                    slot = None
+                if (slot is None and is_hi and ecfg.async_prefetch
+                        and self.scheduler.serves_lo_downgrade(mi, e)):
+                    # issue-time precision downgrade: the staging engine
+                    # replaced this hi copy with a lo one under link
+                    # pressure — compute from the lo pool until an idle-link
+                    # upgrade lands the hi copy
+                    is_hi = False
+                    slot = self.cache.lookup((mi, e), False)
+                    self.scheduler.served_lo_expert_steps += 1
+                if slot is None:
+                    if is_hi:
+                        self.cache.stats.misses_hi += 1
+                    else:
+                        self.cache.stats.misses_lo += 1
+                    buf = self._stage(mi, e, d_)
+                    if is_hi:
+                        ovf["hi_wi"][n_ovf_hi] = buf["wi"]
+                        ovf["hi_wo"][n_ovf_hi] = buf["wo"]
+                        slot = ecfg.hi_slots + n_ovf_hi
+                        n_ovf_hi += 1
+                    else:
+                        for name in ("wi_data", "wi_scale", "wo_data",
+                                     "wo_scale"):
+                            ovf[f"lo_{name}"][n_ovf_lo] = buf[name]
+                        slot = ecfg.lo_slots + n_ovf_lo
+                        n_ovf_lo += 1
+                    self.loader.loaded_bytes += self.expert_bytes[d_]
+                    self.loader.n_loads[d_] += 1
+                    self._union_reloads += 1
+                if is_hi:
+                    hi_rows[n_hi], hi_ranks[n_hi] = r, j
+                    hi_slots[n_hi] = slot
+                    w_hi[r, j] = gates[r][j]
+                    n_hi += 1
+                else:
+                    lo_rows[n_lo], lo_ranks[n_lo] = r, j
+                    lo_slots[n_lo] = slot
+                    w_lo[r, j] = gates[r][j]
+                    n_lo += 1
+                self._served[PREC_HI if is_hi else PREC_LO] += 1
+        return (hi_rows, hi_ranks, hi_slots, lo_rows, lo_ranks, lo_slots,
+                w_hi, w_lo), ovf
 
     # ---- per-expert reference implementation (parity baseline) ----
     def _decode_step_batch_reference(self, tokens) -> np.ndarray:
@@ -1029,7 +1101,7 @@ class OffloadEngine:
             p = self.layer_params[li]
             x = self._attn_layer(li, x, table=table, active_dev=active_dev)
             h = ffn_in(p, x)                                   # (B,1,D)
-            h_host = np.asarray(h[:, 0], np.float32)           # (B,D)
+            h_host = self._to_host(h[:, 0], np.float32)        # (B,D)
 
             # ---- gate (the paper's Expert Scorer input), per slot ----
             tops: Dict[int, np.ndarray] = {}
@@ -1067,6 +1139,7 @@ class OffloadEngine:
                 y = jnp.zeros_like(hr)
                 wsum = 0.0
                 for e, d_, w in zip(tops[r], dec, gates[r]):
+                    self._served[int(d_)] += 1
                     if d_ == PREC_SKIP:
                         continue
                     is_hi = d_ == PREC_HI
@@ -1084,7 +1157,7 @@ class OffloadEngine:
                         self._union_reloads += 1
                     if self.ecfg.compute_mode == "host":
                         out = self._host_expert(mi, int(e), d_,
-                                                np.asarray(hr, np.float32))
+                                                self._to_host(hr, np.float32))
                         out = jnp.asarray(out, hr.dtype)
                     elif is_hi:
                         out = hi_exp(self.pool_hi["wi"][slot],
@@ -1108,7 +1181,7 @@ class OffloadEngine:
         for r in rows:
             self.trace.append(row_trace[r])
         lg = self.model.logits(self.params, x)[:, 0]
-        return np.asarray(lg, np.float32)
+        return self._to_host(lg, np.float32)
 
     def close(self):
         """Release the staging engine's worker threads (also released
@@ -1139,9 +1212,9 @@ class OffloadEngine:
             wi = self.storage_hi[mi]["wi"][e]
             wo = self.storage_hi[mi]["wo"][e]
         else:
-            wi = np.asarray(dequantize(jax.tree_util.tree_map(
+            wi = self._to_host(dequantize(jax.tree_util.tree_map(
                 lambda a: a[e], self.storage_lo[mi]["wi"])))
-            wo = np.asarray(dequantize(jax.tree_util.tree_map(
+            wo = self._to_host(dequantize(jax.tree_util.tree_map(
                 lambda a: a[e], self.storage_lo[mi]["wo"])))
         z = h @ wi
         if cfg.ffn_activation == "swiglu":
@@ -1194,7 +1267,8 @@ class OffloadEngine:
             "loaded_bytes": self.loader.loaded_bytes,
             "pred_accuracy": {int(d): float(a)
                               for d, a in self.predictor.accuracy().items()},
-            "gating_s": self._gating_s,
+            # the gate span's total (grouped decode)
+            "gating_s": self.spans.seconds("hobbit.layer.gate"),
             "expert_dispatches": self._expert_dispatches,
             "union_reloads": self._union_reloads,
             # which kernel implementation each hot-path op dispatched/traced
@@ -1204,6 +1278,14 @@ class OffloadEngine:
             "kernel_dispatch": kops.dispatch_counts(),
             # KV page-pool pressure (zeros under the dense KV layout)
             "kv_pages_used": 0, "kv_pages_total": 0, "kv_page_fraction": 0.0,
+            # {span name: {"s": seconds, "n": count}} (core/spans.py)
+            "spans": self.spans.totals(),
+            "overflow_shipped_bytes": self._overflow_shipped_bytes,
+            "commit_shipped_bytes": self._commit_shipped_bytes,
+            "host_syncs": self._host_syncs,
+            "served_hi": self._served[PREC_HI],
+            "served_lo": self._served[PREC_LO],
+            "served_skip": self._served[PREC_SKIP],
         }
         if self.ecfg.paged_kv and self.kv_pool is not None:
             s.update(self.kv_pool.stats())

@@ -46,12 +46,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import Spans
 from repro.models.kv_pages import PagePoolExhausted
 from repro.models.model import Model
 from repro.serving.api import DenseBackend, InferenceBackend
 from repro.serving.decode import sample_token
 from repro.serving.workload import (DEFAULT_AGING_S, effective_priority,
                                     slo_urgency)
+
+# the scheduler's host spans (core/spans.py, totals in stats()["spans"]): one
+# iteration's admission work, and each sampling call (logits round trip)
+SPANS = ("hobbit.sched.admit", "hobbit.sched.sample")
 
 
 @dataclasses.dataclass
@@ -124,6 +129,8 @@ class BatchingServer:
         self._steps = 0
         self._closed = False
         self._last_backend_stats: Optional[dict] = None
+        # owner: main-thread
+        self.spans = Spans(SPANS)
 
     def submit(self, req: Request):
         """Queue a request.  A pre-set `submitted_at` (a workload trace's
@@ -134,9 +141,10 @@ class BatchingServer:
 
     # ------------------------------------------------------------------
     def _sample(self, logits) -> np.ndarray:
-        self.key, sub = jax.random.split(self.key)
-        return np.asarray(sample_token(jnp.asarray(logits), sub,
-                                       self.temperature))
+        with self.spans("hobbit.sched.sample"):
+            self.key, sub = jax.random.split(self.key)
+            return np.asarray(sample_token(jnp.asarray(logits), sub,
+                                           self.temperature))
 
     def run(self):
         """Serve until queue, admissions and in-flight slots are drained."""
@@ -207,62 +215,68 @@ class BatchingServer:
             for slot in [s for s, r in active.items()
                          if len(outs[s]) >= r.max_new_tokens]:
                 retire(slot)
-            # admission: up to admit_k queued requests prefill concurrently,
-            # each gated on KV capacity for its whole lifetime.  At most one
-            # preemption per scheduler iteration keeps the pause path from
-            # thrashing the batch.
-            order_queue()
-            if self.queue and len(admitting) < self.admit_k:
-                req = self.queue[0]
-                need = len(req.prompt) + req.max_new_tokens + 1
-                blocked = not free or not self.backend.can_admit(
-                    need, prompt=None if getattr(req, "_paused", None)
-                    else req.prompt)
-                if blocked:
-                    try_preempt()       # at most one pause per iteration
-            while free and self.queue and len(admitting) < self.admit_k:
-                req = self.queue[0]
-                need = len(req.prompt) + req.max_new_tokens + 1
-                paused = getattr(req, "_paused", None)
-                # the prompt rides along so paged backends can price the
-                # request net of prefix sharing (aliased prefix = free);
-                # a resuming request restores private pages, so no prompt
-                if not self.backend.can_admit(
-                        need, prompt=None if paused else req.prompt):
-                    if not active and not admitting:
-                        # nothing in flight can ever free capacity for it
-                        raise RuntimeError(
-                            f"request rid={req.rid} needs {need} KV tokens "
-                            "but the drained pool cannot hold it; grow "
-                            "kv_pages / max_len or shrink the request")
-                    break               # wait for a retirement to free pages
-                self.queue.pop(0)
-                slot = free.pop(0)
-                t0 = time.time()
-                if paused is not None:
-                    # resume a preempted victim: KV restored from its host
-                    # snapshot, decode continues where it left off.  The
-                    # snapshot may need a few more pages than can_admit
-                    # priced (aliased prefix pages were copied out private),
-                    # so a failed restore just requeues the victim.
-                    try:
-                        self.backend.resume(slot, paused["snapshot"])
-                    except PagePoolExhausted:
-                        self.queue.insert(0, req)
-                        free.insert(0, slot)
-                        break           # wait for a retirement to free pages
-                    req._paused = None  # type: ignore[attr-defined]
-                    active[slot] = req
-                    outs[slot] = paused["outs"]
-                    pending_tok[slot] = paused["pending_tok"]
-                    self.events.append(("resume", slot, req.rid, step_idx))
-                    continue
-                req.queue_wait_s = t0 - req.submitted_at
-                self.backend.join_begin(slot, np.asarray(req.prompt, np.int32),
-                                        reserve_tokens=need)
-                admitting[slot] = req
-                admit_t0[slot] = t0
-                self.events.append(("admit", slot, req.rid, step_idx))
+            with self.spans("hobbit.sched.admit"):
+                # admission: up to admit_k queued requests prefill
+                # concurrently, each gated on KV capacity for its whole
+                # lifetime.  At most one preemption per scheduler iteration
+                # keeps the pause path from thrashing the batch.
+                order_queue()
+                if self.queue and len(admitting) < self.admit_k:
+                    req = self.queue[0]
+                    need = len(req.prompt) + req.max_new_tokens + 1
+                    blocked = not free or not self.backend.can_admit(
+                        need, prompt=None if getattr(req, "_paused", None)
+                        else req.prompt)
+                    if blocked:
+                        try_preempt()   # at most one pause per iteration
+                while free and self.queue and len(admitting) < self.admit_k:
+                    req = self.queue[0]
+                    need = len(req.prompt) + req.max_new_tokens + 1
+                    paused = getattr(req, "_paused", None)
+                    # the prompt rides along so paged backends can price
+                    # the request net of prefix sharing (aliased prefix =
+                    # free); a resuming request restores private pages, so
+                    # no prompt
+                    if not self.backend.can_admit(
+                            need, prompt=None if paused else req.prompt):
+                        if not active and not admitting:
+                            # nothing in flight can ever free capacity for it
+                            raise RuntimeError(
+                                f"request rid={req.rid} needs {need} KV "
+                                "tokens but the drained pool cannot hold "
+                                "it; grow kv_pages / max_len or shrink the "
+                                "request")
+                        break       # wait for a retirement to free pages
+                    self.queue.pop(0)
+                    slot = free.pop(0)
+                    t0 = time.time()
+                    if paused is not None:
+                        # resume a preempted victim: KV restored from its
+                        # host snapshot, decode continues where it left
+                        # off.  The snapshot may need a few more pages than
+                        # can_admit priced (aliased prefix pages were copied
+                        # out private), so a failed restore just requeues
+                        # the victim.
+                        try:
+                            self.backend.resume(slot, paused["snapshot"])
+                        except PagePoolExhausted:
+                            self.queue.insert(0, req)
+                            free.insert(0, slot)
+                            break   # wait for a retirement to free pages
+                        req._paused = None  # type: ignore[attr-defined]
+                        active[slot] = req
+                        outs[slot] = paused["outs"]
+                        pending_tok[slot] = paused["pending_tok"]
+                        self.events.append(("resume", slot, req.rid,
+                                            step_idx))
+                        continue
+                    req.queue_wait_s = t0 - req.submitted_at
+                    self.backend.join_begin(
+                        slot, np.asarray(req.prompt, np.int32),
+                        reserve_tokens=need)
+                    admitting[slot] = req
+                    admit_t0[slot] = t0
+                    self.events.append(("admit", slot, req.rid, step_idx))
             # one shared call advances every in-progress admission one chunk
             if admitting:
                 done = self.backend.join_step()
@@ -404,5 +418,7 @@ class BatchingServer:
                                if self._steps else 0.0),
             "overlap_fraction": backend_stats.get("overlap_fraction", 0.0),
             "kv_page_fraction": backend_stats.get("kv_page_fraction", 0.0),
+            # {span name: {"s": seconds, "n": count}} (core/spans.py)
+            "spans": self.spans.totals(),
             "backend": backend_stats,
         }
