@@ -242,7 +242,6 @@ class OffloadEngine:
         self._layer_s_ema = 0.0         # per-layer compute EMA (deadline hints)
         self._layer_period_ema = 0.0    # full layer period EMA (stream feed)
         self._closed = False
-        self._ovf_np = None             # lazy overflow staging buffers
         self.batch = 1
         self.max_len = 0
         self.active = np.ones((1,), bool)
@@ -330,18 +329,21 @@ class OffloadEngine:
         entries: [(task_like_with_precision, slot, staged_dict)]."""
         with self.spans("hobbit.stage.commit"):
             for t, slot, buf in entries:
-                if t.precision == PREC_HI:
-                    pool, names = self.pool_hi, ("wi", "wo")
-                else:
-                    pool, names = self.pool_lo, ("wi_data", "wi_scale",
-                                                 "wo_data", "wo_scale")
-                host = [buf[n][None] for n in names]
-                self._commit_shipped_bytes += sum(a.nbytes for a in host)
-                new = self._scatter_fn(len(names))(
-                    [pool[n] for n in names], jnp.asarray([slot], jnp.int32),
-                    [jnp.asarray(a) for a in host])
-                for n, v in zip(names, new):
-                    pool[n] = v
+                pool = self.pool_hi if t.precision == PREC_HI else self.pool_lo
+                self._commit_shipped_bytes += self._write_slot(pool, slot, buf)
+
+    def _write_slot(self, arrays: Dict, slot: int, buf: dict) -> int:
+        """Scatter one staged expert (``_stage``'s buffers, keyed as
+        ``arrays``) into ``slot`` of the device arrays and rebind them; the
+        hi expert crosses in float32 and the scatter casts it on the device.
+        Returns the bytes that crossed."""
+        names = tuple(arrays)
+        host = [buf[n][None] for n in names]
+        new = self._scatter_fn(len(names))(
+            [arrays[n] for n in names], jnp.asarray([slot], jnp.int32),
+            [jnp.asarray(a) for a in host])
+        arrays.update(zip(names, new))
+        return sum(a.nbytes for a in host)
 
     def _to_host(self, x, dtype=None) -> np.ndarray:
         """Read a device array to the host.  Every device->host read of a
@@ -349,24 +351,27 @@ class OffloadEngine:
         self._host_syncs += 1
         return np.asarray(x, dtype)
 
-    def _overflow_buffers(self, pp: int) -> Dict[str, np.ndarray]:
-        """Reusable host staging buffers for union-overflow experts (cache
-        smaller than a layer's union demand).  Stale entries from earlier
-        layers are never addressed: overflow slot indices are only assigned
-        to entries written this layer."""
-        if self._ovf_np is None or self._ovf_np["hi_wi"].shape[0] < pp:
-            qi, qo = self.storage_lo[0]["wi"], self.storage_lo[0]["wo"]
-            d, f = self.cfg.d_model, self.cfg.moe.d_ff_expert
-            wi_cols = self.storage_hi[0]["wi"].shape[-1]
-            self._ovf_np = {
-                "hi_wi": np.zeros((pp, d, wi_cols), np.float32),
-                "hi_wo": np.zeros((pp, f, d), np.float32),
-                "lo_wi_data": np.zeros((pp, *qi.data.shape[1:]), np.int8),
-                "lo_wi_scale": np.zeros((pp, *qi.scale.shape[1:]), np.float32),
-                "lo_wo_data": np.zeros((pp, *qo.data.shape[1:]), np.int8),
-                "lo_wo_scale": np.zeros((pp, *qo.scale.shape[1:]), np.float32),
-            }
-        return self._ovf_np
+    def _overflow_slots(self, pp: int) -> Dict[int, Dict[str, jax.Array]]:
+        """One layer's device overflow slots for union-overflow experts
+        (cache smaller than the layer's union demand), {precision: {name:
+        array}}: ``pp`` zeros per precision made on the device, shaped and
+        typed as the pools.  Made afresh for each layer and dropped after
+        its ``grouped_ffn``: slots kept across layers would sit beside the
+        commits' uploads, which set the step's peak memory.  Only entries
+        written this layer are addressed.  The first slots of a size get an
+        expert written to slot 0 of each, so that their scatter variants
+        compile here and no later step compiles one."""
+        name = f"overflow_slots_{pp}"
+        first = name not in self._jit_cache
+        pools = {PREC_HI: self.pool_hi, PREC_LO: self.pool_lo}
+        slots = self._jit(name, lambda n=pp: {
+            prec: {k: jnp.zeros((n, *a.shape[1:]), a.dtype)
+                   for k, a in pool.items()}
+            for prec, pool in pools.items()})()
+        if first:
+            for prec, arrays in slots.items():
+                self._write_slot(arrays, 0, self._stage(0, 0, prec))
+        return slots
 
     def _fetch_many(self, items: List[Tuple[int, int, int, int]]):
         """Blocking batched fetch into admitted pool slots: items =
@@ -934,34 +939,38 @@ class OffloadEngine:
                 else:
                     self.loader.drain(mi)
                 t_load = time.perf_counter() - t_load0
+                # a commit's upload holds device memory until its scatter
+                # has run: land them all before grouped_ffn is enqueued, or
+                # they sit beside its temporaries and raise peak memory
+                jax.block_until_ready((self.pool_hi, self.pool_lo))
 
             # ---- grouped expert compute: 1 hi + 1 lo dispatch ----
             with sp("hobbit.layer.plan", layer=mi):
                 plan, ovf = self._plan_layer(mi, rows, tops, gates)
 
             with sp("hobbit.layer.ship_overflow", layer=mi):
-                # jnp.asarray with a dtype turns the float32 hi buffers to
-                # the model dtype on the host, before they cross
-                ovf_dev = (jnp.asarray(ovf["hi_wi"], self.dtype),
-                           jnp.asarray(ovf["hi_wo"], self.dtype),
-                           jnp.asarray(ovf["lo_wi_data"]),
-                           jnp.asarray(ovf["lo_wi_scale"]),
-                           jnp.asarray(ovf["lo_wo_data"]),
-                           jnp.asarray(ovf["lo_wo_scale"]))
-                self._overflow_shipped_bytes += sum(a.nbytes
-                                                    for a in ovf_dev)
+                # only the experts this layer reloaded cross, each into its
+                # overflow slot
+                slots = self._overflow_slots(b * k)
+                for prec, i, buf in ovf:
+                    self._overflow_shipped_bytes += self._write_slot(
+                        slots[prec], i, buf)
+                if ovf:
+                    # landed before grouped_ffn, as the commits are
+                    jax.block_until_ready(slots)
 
             with sp("hobbit.layer.ffn", layer=mi):
                 y = grouped_ffn(self.pool_hi["wi"], self.pool_hi["wo"],
                                 self.pool_lo["wi_data"],
                                 self.pool_lo["wi_scale"],
                                 self.pool_lo["wo_data"],
-                                self.pool_lo["wo_scale"], *ovf_dev, h,
+                                self.pool_lo["wo_scale"],
+                                *slots[PREC_HI].values(),
+                                *slots[PREC_LO].values(), h,
                                 *(jnp.asarray(a) for a in plan))
-                # free the device copies now, as when they were arguments
-                # only: held into the next layer they would sit beside its
-                # own and raise peak memory by a layer's buffers
-                del ovf_dev
+                # freed once grouped_ffn has run, before the next layer's
+                # commits
+                del slots
                 self._expert_dispatches += 1
                 x = combine_fn(x, y)
 
@@ -996,14 +1005,16 @@ class OffloadEngine:
         lo GEMM or neither, by the precision actually served, and count it
         in ``served_*``.  Returns ``_grouped_ffn``'s index and weight arrays
         (hi_rows, hi_ranks, hi_slots, lo_rows, lo_ranks, lo_slots, w_hi,
-        w_lo; fixed length P = batch * top_k) and the host overflow buffers.
+        w_lo; fixed length P = batch * top_k) and the union-overflow
+        experts to ship, [(precision, overflow slot, staged buffers)].
 
         Union-overflow pairs (a same-layer neighbour's admission evicted this
-        expert: union demand > pool) ride in per-layer overflow staging
-        buffers appended after the pool slots instead of re-admitting —
-        re-admission could evict a slot an earlier pair already claimed,
-        corrupting its compute.  The re-fetch still counts as a miss + load
-        so hit_ratio reflects real traffic under contention."""
+        expert: union demand > pool) ride in the device overflow slots
+        (``_overflow_slots``), addressed after the pool slots, instead of
+        re-admitting — re-admission could evict a slot an earlier pair
+        already claimed, corrupting its compute.  The re-fetch still counts
+        as a miss + load so hit_ratio reflects real traffic under
+        contention."""
         ecfg = self.ecfg
         b, k = self.batch, self.cfg.moe.top_k
         pp = b * k
@@ -1015,7 +1026,7 @@ class OffloadEngine:
         lo_slots = np.zeros(pp, np.int32)
         w_hi = np.zeros((b, k), np.float32)
         w_lo = np.zeros((b, k), np.float32)
-        ovf = self._overflow_buffers(pp)
+        ovf = []
         n_hi = n_lo = 0
         n_ovf_hi = n_ovf_lo = 0
         for r in rows:
@@ -1044,24 +1055,21 @@ class OffloadEngine:
                     slot = self.cache.lookup((mi, e), False)
                     self.scheduler.served_lo_expert_steps += 1
                 if slot is None:
+                    # staged in the precision served: a downgraded hi pair
+                    # reloads its lo copy
+                    prec = PREC_HI if is_hi else PREC_LO
                     if is_hi:
                         self.cache.stats.misses_hi += 1
-                    else:
-                        self.cache.stats.misses_lo += 1
-                    buf = self._stage(mi, e, d_)
-                    if is_hi:
-                        ovf["hi_wi"][n_ovf_hi] = buf["wi"]
-                        ovf["hi_wo"][n_ovf_hi] = buf["wo"]
+                        ovf.append((prec, n_ovf_hi, self._stage(mi, e, prec)))
                         slot = ecfg.hi_slots + n_ovf_hi
                         n_ovf_hi += 1
                     else:
-                        for name in ("wi_data", "wi_scale", "wo_data",
-                                     "wo_scale"):
-                            ovf[f"lo_{name}"][n_ovf_lo] = buf[name]
+                        self.cache.stats.misses_lo += 1
+                        ovf.append((prec, n_ovf_lo, self._stage(mi, e, prec)))
                         slot = ecfg.lo_slots + n_ovf_lo
                         n_ovf_lo += 1
-                    self.loader.loaded_bytes += self.expert_bytes[d_]
-                    self.loader.n_loads[d_] += 1
+                    self.loader.loaded_bytes += self.expert_bytes[prec]
+                    self.loader.n_loads[prec] += 1
                     self._union_reloads += 1
                 if is_hi:
                     hi_rows[n_hi], hi_ranks[n_hi] = r, j

@@ -409,7 +409,7 @@ class StagingEngine:
             if self.cache.lookup(key, is_hi) is not None:
                 continue                      # resident or already in flight
             if (is_hi and self.upgrade
-                    and self.serves_lo_downgrade(layer, int(e))):
+                    and self._downgraded(layer, int(e))):
                 # lo-substituted expert: its promotion belongs to the
                 # idle-link upgrade pass, not the deadline path — a deadline
                 # hi prefetch here would re-add the bytes the downgrade shed
@@ -809,9 +809,16 @@ class StagingEngine:
         self.downgraded = {k for k in self.downgraded if k[0] != layer}
 
     def serves_lo_downgrade(self, layer: int, expert: int) -> bool:
-        """True when (layer, expert)'s hi copy was downgraded away and its
-        lo stand-in is resident — the compute path should read the lo pool
-        instead of blocking on an on-demand hi load.
+        """The compute path's query: True when (layer, expert)'s hi copy was
+        downgraded away and its lo stand-in is resident, so the layer is
+        served from the lo pool instead of blocking on an on-demand hi load.
+        Its answers are the precisions served; the staging engine's own
+        checks ask ``_downgraded`` (the state can change before compute)."""
+        return self._downgraded(layer, expert)
+
+    def _downgraded(self, layer: int, expert: int) -> bool:
+        """Whether (layer, expert)'s hi copy is downgraded to a resident lo
+        stand-in right now.
 
         Upgrade pass ON: the substitution persists across decode steps and
         ends the moment a hi copy has fully landed next to the lo one (hi
@@ -853,7 +860,7 @@ class StagingEngine:
         todo = []
         for t in tasks:
             is_hi = t.precision == PREC_HI
-            if is_hi and self.serves_lo_downgrade(t.layer, t.expert):
+            if is_hi and self._downgraded(t.layer, t.expert):
                 continue  # issue-time downgrade: compute reads the lo copy
             if self.cache.lookup((t.layer, t.expert), is_hi) is not None:
                 continue  # duplicate across batch slots / raced with prefetch

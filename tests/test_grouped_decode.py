@@ -223,6 +223,46 @@ def test_union_overflow_reload_stays_correct_at_batch2(setup):
         np.testing.assert_array_equal(res_b.tokens[r], res_1.tokens[0])
 
 
+def test_overflow_slots_keep_their_shapes_and_ship_only_reloads(setup):
+    """The union-overflow contention of the test above over steps whose
+    reload counts differ, down to none once one row is left (its two
+    experts fit the two hi slots): each step ships overflow bytes exactly
+    when it reloads, `grouped_ffn` and each slot scatter compile once
+    whatever the count, and every row's tokens still equal its isolated
+    batch-1 run's."""
+    m, params = setup
+    ecfg = EngineConfig(hi_slots=2, lo_slots=1, thresholds=Thresholds(1.0, 1.0),
+                        prefetch=False)
+    prompts = np.random.default_rng(16).integers(0, 256, (2, 6))
+    eng = OffloadEngine(m, params, ecfg)
+    be = HobbitBackend(eng)
+    be.start_batch(2, 32)
+    toks = [np.argmax(be.prefill(prompts), -1).astype(np.int32)]
+    reloads, shipped, compiled = [], [], []
+    for t in range(6):
+        if t == 3:
+            be.release(1)
+        s0 = eng.stats()
+        toks.append(np.argmax(be.step(toks[-1]), -1).astype(np.int32))
+        s1 = eng.stats()
+        reloads.append(s1["union_reloads"] - s0["union_reloads"])
+        shipped.append(s1["overflow_shipped_bytes"]
+                       - s0["overflow_shipped_bytes"])
+        compiled.append({k: f._cache_size() for k, f in eng._jit_cache.items()})
+    assert 0 in reloads and len(set(reloads)) > 1, reloads
+    assert [b > 0 for b in shipped] == [n > 0 for n in reloads], (reloads,
+                                                                   shipped)
+    assert compiled[0]["grouped_ffn"] == 1
+    # each scatter at most twice: the pools' shape and the overflow slots'
+    assert all(compiled[0][("scatter", n)] <= 2 for n in (2, 4))
+    assert all(c == compiled[0] for c in compiled), compiled
+    toks = np.stack(toks, axis=1)                      # (2, 7)
+    for r, n in ((0, 7), (1, 4)):
+        res_1 = generate(HobbitBackend(OffloadEngine(m, params, ecfg)),
+                         prompts[r : r + 1], n, max_len=32)
+        np.testing.assert_array_equal(toks[r, :n], res_1.tokens[0, 6:])
+
+
 def test_select_victim_when_everything_hard_pinned():
     """Pool smaller than one layer's pinned set: admission must still
     succeed by sacrificing a hard-pinned resident (it reloads on demand)."""

@@ -43,11 +43,12 @@ def setup():
     return _model("float32")
 
 
-def _backend(setup, **ecfg):
+def _backend(setup, hi_slots=4, lo_slots=4, **ecfg):
     m, params = setup
     return make_backend(BackendConfig(
         kind="hobbit", paged=True, page_size=4, prefill_chunk=8,
-        engine=EngineConfig(hi_slots=4, lo_slots=4, **ecfg)), m, params)
+        engine=EngineConfig(hi_slots=hi_slots, lo_slots=lo_slots, **ecfg)),
+        m, params)
 
 
 def _decode(be, steps=STEPS, batch=2, seed=0):
@@ -98,19 +99,35 @@ def test_children_never_exceed_their_parent(run):
     assert all(v >= 0.0 for v in s.values())
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_overflow_bytes_are_the_buffers_once_per_moe_layer(dtype):
-    be = _backend(_model(dtype))
+@pytest.mark.parametrize("dtype,hi_slots,lo_slots", [
+    ("float32", 2, 1), ("bfloat16", 2, 1),
+    # every expert of every layer fits: no reload, nothing crosses
+    ("float32", 16, 16)])
+def test_overflow_bytes_are_the_reloaded_experts(dtype, hi_slots, lo_slots):
+    be = _backend(_model(dtype), hi_slots=hi_slots, lo_slots=lo_slots)
     eng = be.engine
+    assert eng.num_moe_layers * eng.cfg.moe.num_experts <= 16
+    reloaded = []
+    plan_layer = eng._plan_layer
+
+    def recording(*args):
+        plan, ovf = plan_layer(*args)
+        reloaded.extend(prec for prec, _, _ in ovf)
+        return plan, ovf
+
+    eng._plan_layer = recording
     before, after = _decode(be)
-    # the float32 hi host buffers cross in the model dtype, the lo ones as
-    # they are
-    itemsize = np.dtype(eng.dtype).itemsize
-    per_layer = sum(a.size * itemsize if n.startswith("hi_") else a.nbytes
-                    for n, a in eng._ovf_np.items())
-    assert per_layer > 0
+    assert len(reloaded) == _delta(before, after, "union_reloads")
+    assert (len(reloaded) > 0) == (hi_slots < 16)
+    # a reloaded hi expert crosses as its float32 host copy, a lo one as
+    # stored
+    hi, lo = eng.storage_hi[0], eng.storage_lo[0]
+    nbytes = {PREC_HI: hi["wi"][0].nbytes + hi["wo"][0].nbytes,
+              PREC_LO: sum(a.nbytes for q in (lo["wi"], lo["wo"])
+                           for a in (q.data[0], q.scale[0]))}
+    assert hi["wi"].dtype == np.float32
     assert (_delta(before, after, "overflow_shipped_bytes")
-            == STEPS * eng.num_moe_layers * per_layer)
+            == sum(nbytes[p] for p in reloaded))
     be.close()
 
 

@@ -16,7 +16,7 @@ import pytest
 from repro.core import (EngineConfig, LRU, MultidimensionalCache,
                         OffloadEngine, PREC_HI, PREC_LO, StagingEngine,
                         Thresholds)
-from repro.core.loader import DynamicExpertLoader
+from repro.core.loader import ON_DEMAND, DynamicExpertLoader, LoadTask
 from repro.core.simulator import (HobbitSimConfig, OffloadSimulator, RTX4090,
                                   TraceLayer)
 from repro.configs import get_config, smoke_variant
@@ -153,6 +153,26 @@ def test_upgrade_promotes_downgraded_expert_in_place():
     assert (1, 1) not in eng.lo_substituted
     precs = sorted(t.precision for t, _, _ in committed)
     assert precs == sorted([PREC_HI, PREC_HI, PREC_LO])
+    eng.shutdown()
+
+
+def test_staging_checks_do_not_ask_the_compute_query():
+    """`serves_lo_downgrade` answers the compute path, so its answers are
+    the precisions served: the staging engine's own downgrade checks (a
+    prefetch for the expert, an on-demand hi miss) skip the downgraded
+    expert without asking it, since the substitution can end before the
+    layer computes."""
+    eng, cache, staged, committed = _unit_engine(link_bps=1e6,
+                                                 stage_sleep=0.05)
+    _downgrade_one(eng, cache)
+    eng.retire_layer(1)
+    asked = []
+    eng.serves_lo_downgrade = lambda *key: asked.append(key)
+    assert eng.submit_prefetch(1, [1], np.array([PREC_HI]), current_layer=0,
+                               gates=np.array([0.9])) == 0
+    miss = LoadTask(1, 1, PREC_HI, ON_DEMAND, HI_BYTES)
+    assert eng.drain_on_demand([miss], 1) == []
+    assert asked == []
     eng.shutdown()
 
 
