@@ -182,7 +182,7 @@ class OffloadEngine:
             hi, lo = {}, {}
             for name in ("wi", "wo"):
                 hi[name], lo[name] = self._host_copies(experts[name], lead,
-                                                       e, ecfg)
+                                                       e, ecfg, self.dtype)
             # host storage lives in numpy so background staging threads never
             # issue device computations
             self.storage_hi.append(hi)
@@ -252,16 +252,17 @@ class OffloadEngine:
 
     @staticmethod
     def _host_copies(leaf, lead: Tuple[int, ...], num_experts: int,
-                     ecfg: EngineConfig):
-        """(hi float32, lo QTensor) host copies of one expert matrix stack:
-        expert j is ``leaf[(*lead, j)]``, sliced, quantized and fetched
-        alone."""
+                     ecfg: EngineConfig, dtype):
+        """(hi, lo QTensor) host copies of one expert matrix stack: expert j
+        is ``leaf[(*lead, j)]``, sliced, quantized and fetched alone.  hi is
+        kept in ``dtype``, the hi pool's, so a commit ships exactly the bytes
+        the pool holds and nothing is converted on the host after this."""
         hi, data, scale, q = None, [], [], None
         for j in range(num_experts):
             w = leaf[(*lead, j)]
             if hi is None:
-                hi = np.empty((num_experts, *w.shape), np.float32)
-            hi[j] = np.asarray(w, np.float32)
+                hi = np.empty((num_experts, *w.shape), dtype)
+            hi[j] = np.asarray(w, dtype)
             q = quantize(w, bits=ecfg.lo_bits, group_size=ecfg.group_size)
             data.append(np.asarray(q.data))
             scale.append(np.asarray(q.scale))
@@ -334,9 +335,9 @@ class OffloadEngine:
 
     def _write_slot(self, arrays: Dict, slot: int, buf: dict) -> int:
         """Scatter one staged expert (``_stage``'s buffers, keyed as
-        ``arrays``) into ``slot`` of the device arrays and rebind them; the
-        hi expert crosses in float32 and the scatter casts it on the device.
-        Returns the bytes that crossed."""
+        ``arrays``) into ``slot`` of the device arrays and rebind them; each
+        buffer crosses as stored on the host (hi in the pool's dtype, so the
+        scatter's cast is a no-op).  Returns the bytes that crossed."""
         names = tuple(arrays)
         host = [buf[n][None] for n in names]
         new = self._scatter_fn(len(names))(
@@ -1217,8 +1218,9 @@ class OffloadEngine:
         """CPU-GPU cooperative mode (§4): run the expert on host weights."""
         cfg = self.cfg
         if d_ == PREC_HI:
-            wi = self.storage_hi[mi]["wi"][e]
-            wo = self.storage_hi[mi]["wo"][e]
+            # numpy math in bfloat16 is slow and rounds per op: upcast
+            wi = np.asarray(self.storage_hi[mi]["wi"][e], np.float32)
+            wo = np.asarray(self.storage_hi[mi]["wo"][e], np.float32)
         else:
             wi = self._to_host(dequantize(jax.tree_util.tree_map(
                 lambda a: a[e], self.storage_lo[mi]["wi"])))

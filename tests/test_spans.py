@@ -13,7 +13,7 @@ import pytest
 
 from repro.configs import get_config, smoke_variant
 from repro.core import EngineConfig
-from repro.core.engine import SPANS
+from repro.core.engine import SPANS, _expert_refs
 from repro.core.scoring import (PREC_HI, PREC_LO, PREC_SKIP,
                                 precision_decisions)
 from repro.core.spans import Spans
@@ -119,13 +119,13 @@ def test_overflow_bytes_are_the_reloaded_experts(dtype, hi_slots, lo_slots):
     before, after = _decode(be)
     assert len(reloaded) == _delta(before, after, "union_reloads")
     assert (len(reloaded) > 0) == (hi_slots < 16)
-    # a reloaded hi expert crosses as its float32 host copy, a lo one as
-    # stored
+    # a reloaded hi expert crosses as its host copy, in the model's dtype, a
+    # lo one as stored
     hi, lo = eng.storage_hi[0], eng.storage_lo[0]
     nbytes = {PREC_HI: hi["wi"][0].nbytes + hi["wo"][0].nbytes,
               PREC_LO: sum(a.nbytes for q in (lo["wi"], lo["wo"])
                            for a in (q.data[0], q.scale[0]))}
-    assert hi["wi"].dtype == np.float32
+    assert hi["wi"].dtype == hi["wo"].dtype == np.dtype(dtype)
     assert (_delta(before, after, "overflow_shipped_bytes")
             == sum(nbytes[p] for p in reloaded))
     be.close()
@@ -145,6 +145,39 @@ def test_commit_bytes_count_what_the_commits_hand_over(setup):
     s1 = eng.stats()
     assert _delta(s0, s1, "commit_shipped_bytes") == want
     assert _delta(s0, s1, "spans", "hobbit.stage.commit", "n") == 1
+    be.close()
+
+
+def _bits(a):
+    a = np.ascontiguousarray(a)
+    return a.view(np.uint8).tobytes(), a.dtype, a.shape
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_hi_host_copy_is_the_models_dtype_bit_for_bit(dtype):
+    # the host keeps hi in the pool's dtype: bf16 ships the 16-bit bytes the
+    # link model counts, float32 stays float32, and a commit lands the
+    # device leaf's values bit for bit either way
+    m, params = _model(dtype)
+    be = _backend((m, params))
+    eng = be.engine
+    li, mi, e = eng.moe_layers[0], 0, 1
+    experts, lead = _expert_refs(m.cfg, params)[li]
+    hi = eng.storage_hi[mi]
+    for name in ("wi", "wo"):
+        assert hi[name].dtype == eng.dtype == np.dtype(dtype)
+        assert _bits(hi[name][e]) == _bits(experts[name][(*lead, e)])
+    be.start_batch(1, 16)
+    s0 = eng.stats()
+    eng._fetch_many([(mi, e, PREC_HI, 0)])
+    s1 = eng.stats()
+    for name in ("wi", "wo"):
+        assert _bits(eng.pool_hi[name][0]) == _bits(hi[name][e])
+    shipped = _delta(s0, s1, "commit_shipped_bytes")
+    assert shipped == hi["wi"][e].nbytes + hi["wo"][e].nbytes
+    # one definition of a hi expert's bytes where the model is 16-bit; a
+    # float32 model ships its 4-byte values, as before
+    assert shipped == eng.expert_bytes[PREC_HI] * np.dtype(dtype).itemsize // 2
     be.close()
 
 
