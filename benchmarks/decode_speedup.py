@@ -75,7 +75,7 @@ def wall_clock_rows(kind, model, params, *, batch=4, steps=24):
         model, params, seqs, EngineConfig(**kw), steps=steps)
     ref, _ = _wall_clock_decode(
         model, params, seqs,
-        EngineConfig(grouped=False, async_prefetch=False, **kw), steps=steps)
+        EngineConfig(grouped=False, **kw), steps=steps)
     return [
         (f"wallclock_decode_tok_s[{kind}][b{batch}][grouped]",
          round(grouped, 2), "tok/s (this host, batched grouped path)"),

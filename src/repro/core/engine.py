@@ -13,6 +13,12 @@ kernels run compiled; on the CPU backend "device" and "host" share memory,
 so only counts and numerics mean anything there.  It also records a routing
 trace that core.simulator replays against hardware cost models — the
 separation the paper uses for its Fig. 9 analysis.
+
+One KV layout (the paged pool of models/kv_pages.py, prompts prefilled in
+chunks), one loader per decode path (the grouped path stages through
+StagingEngine, the per-expert reference drains DynamicExpertLoader
+synchronously) and one device write of an expert (``_stage`` then
+``_commit_staged``, one ``_write_slot`` scatter per expert).
 """
 
 from __future__ import annotations
@@ -38,7 +44,8 @@ from repro.core.spans import Spans
 from repro.kernels import ops as kops
 from repro.models import layers as L
 from repro.models import unstack_layers
-from repro.models.model import Batch, Model
+from repro.models.kv_pages import ChunkedPrefill
+from repro.models.model import Model, supports_paged_kv
 from repro.quant.quantize import QTensor, dequantize, expert_nbytes, quantize
 
 # the engine's host spans (core/spans.py, totals in stats()["spans"]): the
@@ -90,15 +97,12 @@ class EngineConfig:
     group_size: int = 64
     dynamic_loading: bool = True     # ablation switch (Fig. 16)
     prefetch: bool = True            # ablation switch (Fig. 17)
-    compute_mode: str = "device"     # device | host (CPU-helper mode §4)
     # grouped decode: one batched gating matmul + one batched hi GEMM + one
     # batched lo dequant-GEMM per MoE layer instead of O(batch*top_k) tiny
-    # per-expert dispatches.  False selects the original per-expert path
-    # (the parity reference; also used automatically in host compute mode).
+    # per-expert dispatches, with prefetch copies staged in the background
+    # so they overlap compute.  False selects the per-expert path, which
+    # loads synchronously (the parity reference).
     grouped: bool = True
-    # stage prefetch copies on a background executor so they overlap compute
-    # in wall clock (double-buffered).  False drains them synchronously.
-    async_prefetch: bool = True
     # multi-stream staging (core/loader.py StagingEngine): number of copy
     # streams sharing the modeled H2D link (default one hi- + one lo-
     # precision stream).  `ordered=True` with `streams=1` reproduces the
@@ -128,15 +132,14 @@ class EngineConfig:
     link_gbps: Optional[float] = None
     # paged KV cache: slots draw kv_page_size-token pages from a shared pool
     # of kv_pages pages (None = the dense equivalent, batch*ceil(max_len/
-    # page)) instead of each slot allocating max_len up front; prompts then
-    # prefill in prefill_chunk-token chunks (see models/kv_pages.py).
-    paged_kv: bool = False
+    # page)) as they grow; prompts prefill in prefill_chunk-token chunks
+    # (see models/kv_pages.py).
     kv_page_size: int = 64
     kv_pages: Optional[int] = None
     prefill_chunk: int = 64
-    # prefix sharing (paged KV only): admissions alias trie-matched prompt
-    # prefix pages across slots with copy-on-write on divergence; admission
-    # then only charges the unshared suffix (see models/kv_pages.py)
+    # prefix sharing: admissions alias trie-matched prompt prefix pages
+    # across slots with copy-on-write on divergence; admission then only
+    # charges the unshared suffix (see models/kv_pages.py)
     prefix_sharing: bool = True
 
 
@@ -144,6 +147,10 @@ class OffloadEngine:
     def __init__(self, model: Model, params, ecfg: EngineConfig):
         cfg = model.cfg
         assert cfg.moe is not None, "OffloadEngine requires a MoE model"
+        if not supports_paged_kv(cfg):
+            raise ValueError(
+                f"OffloadEngine serves paged KV only, which arch {cfg.name} "
+                "lacks: it needs all-'attn' layers, no MLA, no encoder")
         self.model = model
         self.cfg = cfg
         self.ecfg = ecfg
@@ -223,7 +230,7 @@ class OffloadEngine:
         self.loader = DynamicExpertLoader(
             self.cache, ecfg.thresholds if ecfg.dynamic_loading
             else Thresholds(1.0, 1.0),
-            self._fetch, lambda prec: self.expert_bytes[prec])
+            self._write_expert, lambda prec: self.expert_bytes[prec])
         link_bps = (ecfg.link_gbps * 1e9 if ecfg.link_gbps
                     else measure_link_bps(hi_b))
         self.scheduler = StagingEngine(
@@ -245,9 +252,8 @@ class OffloadEngine:
         self.batch = 1
         self.max_len = 0
         self.active = np.ones((1,), bool)
-        self.kv_pool = None             # PagedKVPool when ecfg.paged_kv
-        self._admission = None          # ChunkedPrefill when ecfg.paged_kv
-        self._pending_joins = {}        # dense-path incremental admissions
+        self.kv_pool = None             # PagedKVPool, made by start_batch
+        self._admission = None          # its ChunkedPrefill
         self._unclaimed_joins = {}      # finished during a blocking join()
 
     @staticmethod
@@ -273,24 +279,15 @@ class OffloadEngine:
     # ------------------------------------------------------------------
     # device transfer
     # ------------------------------------------------------------------
-    def _fetch(self, moe_idx: int, expert: int, precision: int, slot: int):
-        """Write one expert's weights into a pool slot (the 'cudaMemcpy')."""
-        if precision == PREC_HI:
-            src = self.storage_hi[moe_idx]
-            self.pool_hi["wi"] = self.pool_hi["wi"].at[slot].set(
-                jnp.asarray(src["wi"][expert], self.dtype))
-            self.pool_hi["wo"] = self.pool_hi["wo"].at[slot].set(
-                jnp.asarray(src["wo"][expert], self.dtype))
-        else:
-            src = self.storage_lo[moe_idx]
-            self.pool_lo["wi_data"] = self.pool_lo["wi_data"].at[slot].set(
-                src["wi"].data[expert])
-            self.pool_lo["wi_scale"] = self.pool_lo["wi_scale"].at[slot].set(
-                src["wi"].scale[expert])
-            self.pool_lo["wo_data"] = self.pool_lo["wo_data"].at[slot].set(
-                src["wo"].data[expert])
-            self.pool_lo["wo_scale"] = self.pool_lo["wo_scale"].at[slot].set(
-                src["wo"].scale[expert])
+    def _write_expert(self, moe_idx: int, expert: int, precision: int,
+                      slot: int):
+        """Write one expert's weights into a pool slot (the 'cudaMemcpy'):
+        the synchronous loader's ``fetch_fn`` and the reference path's
+        union reload, through the same stage and commit as a staged copy."""
+        t = LoadTask(moe_idx, expert, precision, ON_DEMAND,
+                     self.expert_bytes[precision])
+        self._commit_staged([(t, slot, self._stage(moe_idx, expert,
+                                                   precision))])
 
     def _stage(self, moe_idx: int, expert: int, precision: int) -> dict:
         """Gather one expert's weight bytes from host storage into staging
@@ -378,9 +375,8 @@ class OffloadEngine:
         """Blocking batched fetch into admitted pool slots: items =
         [(moe_idx, expert, precision, slot)], one scatter per expert.
         The decode hot paths go through `_stage`/`_commit_staged` directly
-        (async prefetch, batched on-demand drain, overflow staging); this is
-        the standalone batched-fetch entry point for warmup/pre-population
-        and tests."""
+        (staged prefetch, batched on-demand drain); this is the standalone
+        batched-fetch entry point for warmup/pre-population and tests."""
         entries = []
         for mi, e, prec, slot in items:
             t = LoadTask(mi, e, int(prec), ON_DEMAND, self.expert_bytes[int(prec)])
@@ -392,35 +388,23 @@ class OffloadEngine:
     # ------------------------------------------------------------------
     # jitted pieces
     # ------------------------------------------------------------------
-    def _attn_step(self, p, x, cache, positions):
-        cfg = self.cfg
-        h = L.apply_norm(p["pre_norm"], x, cfg)
-        out, new_cache = L.attn_decode(p["attn"], h, cache, positions, cfg, "attn")
-        return x + out, new_cache
-
     def _attn_step_paged(self, p, x, kp, vp, table, positions, active):
-        """Paged-KV attention step: same residual math as `_attn_step`, but
-        K/V scatter/gather through the shared page pool."""
+        """Paged-KV attention step: pre-norm, attention with K/V scattered
+        and gathered through the shared page pool, and the residual add."""
         cfg = self.cfg
         h = L.apply_norm(p["pre_norm"], x, cfg)
         out, kp, vp = L.paged_attn_decode(p["attn"], h, kp, vp, table,
                                           positions, active, cfg)
         return x + out, kp, vp
 
-    def _attn_layer(self, li: int, x, *, table=None, active_dev=None):
-        """Run layer li's attention against whichever KV layout is active,
-        updating the layout's state in place.  Returns the residual stream."""
-        p = self.layer_params[li]
-        if self.ecfg.paged_kv:
-            # page buffers donated: rebound to the outputs right below
-            fn = self._jit("attn_paged", self._attn_step_paged,
-                           donate=(2, 3))
-            x, kp, vp = fn(p, x, self.kv_pool.k[li], self.kv_pool.v[li],
-                           table, self.positions, active_dev)
-            self.kv_pool.k[li], self.kv_pool.v[li] = kp, vp
-            return x
-        fn = self._jit("attn", self._attn_step)
-        x, self.kv_cache[li] = fn(p, x, self.kv_cache[li], self.positions)
+    def _attn_layer(self, li: int, x, table, active_dev):
+        """Run layer li's attention against the page pool, updating its
+        pages in place.  Returns the residual stream."""
+        # page buffers donated: rebound to the outputs right below
+        fn = self._jit("attn_paged", self._attn_step_paged, donate=(2, 3))
+        x, kp, vp = fn(self.layer_params[li], x, self.kv_pool.k[li],
+                       self.kv_pool.v[li], table, self.positions, active_dev)
+        self.kv_pool.k[li], self.kv_pool.v[li] = kp, vp
         return x
 
     def _paged_step_prologue(self, rows):
@@ -539,7 +523,7 @@ class OffloadEngine:
     # decode
     # ------------------------------------------------------------------
     def start_batch(self, batch: int, max_len: int):
-        """Allocate per-slot KV caches and reset serving state for a new
+        """Allocate the KV page pool and reset serving state for a new
         (possibly multi-request) batch.  All slots start active; continuous-
         batching schedulers toggle individual slots via join()/release()."""
         self._check_open()
@@ -547,85 +531,41 @@ class OffloadEngine:
         self.max_len = max_len
         self.scheduler.flush()          # land any cross-batch in-flight loads
         self.cache.new_sequence()
-        if self.ecfg.paged_kv:
-            from repro.models.kv_pages import ChunkedPrefill
-            self.kv_cache = None
-            self.kv_pool = self.model.init_cache(
-                batch, max_len, paged=True,
-                page_size=self.ecfg.kv_page_size,
-                num_pages=self.ecfg.kv_pages,
-                prefix_sharing=self.ecfg.prefix_sharing)
-            self._admission = ChunkedPrefill(self.model, self.params,
-                                             self.kv_pool,
-                                             chunk=self.ecfg.prefill_chunk)
-        else:
-            self.kv_cache = [
-                {"k": jnp.zeros((batch, max_len, self.cfg.num_kv_heads,
-                                 self.cfg.resolved_head_dim), self.dtype),
-                 "v": jnp.zeros((batch, max_len, self.cfg.num_kv_heads,
-                                 self.cfg.resolved_head_dim), self.dtype)}
-                for _ in range(self.cfg.num_layers)]
+        self.kv_pool = self.model.init_cache(
+            batch, max_len, paged=True, page_size=self.ecfg.kv_page_size,
+            num_pages=self.ecfg.kv_pages,
+            prefix_sharing=self.ecfg.prefix_sharing)
+        self._admission = ChunkedPrefill(self.model, self.params,
+                                         self.kv_pool,
+                                         chunk=self.ecfg.prefill_chunk)
         self.positions = jnp.zeros((batch,), jnp.int32)
         self.active = np.ones((batch,), bool)
         self.trace = []
         self._pending_preds = []        # (Prediction, made_at_layer, slot)
-        self._pending_joins = {}        # abandoned admissions don't leak
         self._unclaimed_joins = {}
 
     def start_sequence(self, max_len: int, batch: int = 1):
         self.start_batch(batch, max_len)
 
     # ---------------- prefill / slot admission ----------------
-    def _prefill_fn(self):
-        key = ("prefill", self.max_len)
-        if key not in self._jit_cache:
-            max_len = self.max_len
-            self._jit_cache[key] = jax.jit(
-                lambda p, b: self.model.prefill(p, b, max_len))
-        return self._jit_cache[key]
-
-    def _flat_decode_cache(self, cache):
-        """Flatten model.prefill's nested cache into the engine's per-layer
-        list.  Valid for the engine's model class: every layer is a full-
-        window "attn" + MoE block, so every entry is a max_len k/v pair."""
-        cfg = self.cfg
-        assert all(k == "attn" for k in cfg.layer_kinds()), cfg.layer_kinds()
-        flat = [dict(c) for c in cache["prefix"]]
-        for bi in range(cfg.num_blocks):
-            for j in range(cfg.period):
-                flat.append(jax.tree_util.tree_map(lambda a, bi=bi: a[bi],
-                                                   cache["blocks"][j]))
-        flat.extend(dict(c) for c in cache["tail"])
-        return flat
-
     def prefill_batch(self, prompts) -> np.ndarray:
-        """Real prefill: run the whole prompt batch through the dense model
-        in one jitted call (prefill is compute-bound and touches every expert
-        anyway — the offload cache only serves the decode phase, matching the
-        paper's deployment), then adopt the KV cache in the engine's
-        per-layer layout.  Returns last-token logits (B, V)."""
+        """Real prefill: chunked prefill of the whole prompt batch through
+        the page pool, computed by the dense model (prefill is compute-bound
+        and touches every expert anyway — the offload cache only serves the
+        decode phase, matching the paper's deployment).  Returns last-token
+        logits (B, V)."""
         self._check_open()
         prompts = np.asarray(prompts, np.int32)
         b, s = prompts.shape
         assert b == self.batch, (b, self.batch)
-        if self.ecfg.paged_kv:
-            # chunked prefill through the page pool, still dense compute
-            for r in range(b):
-                self._admission.begin(r, prompts[r],
-                                      reserve_tokens=self.max_len)
-            done = {}
-            while len(done) < b:
-                done.update(self._admission.step())
-            self.positions = jnp.full((b,), s, jnp.int32)
-            self.active[:] = True
-            return np.stack([done[r] for r in range(b)])
-        batch = Batch(tokens=jnp.asarray(prompts),
-                      loss_mask=jnp.ones((b, s), jnp.float32))
-        logits, cache, positions = self._prefill_fn()(self.params, batch)
-        self.kv_cache = self._flat_decode_cache(cache)
-        self.positions = positions
+        for r in range(b):
+            self._admission.begin(r, prompts[r], reserve_tokens=self.max_len)
+        done = {}
+        while len(done) < b:
+            done.update(self._admission.step())
+        self.positions = jnp.full((b,), s, jnp.int32)
         self.active[:] = True
-        return np.asarray(logits, np.float32)
+        return np.stack([done[r] for r in range(b)])
 
     def join(self, slot: int, prompt) -> np.ndarray:
         """Admit one request into a free slot mid-flight (blocking).  This is
@@ -637,116 +577,74 @@ class OffloadEngine:
         from repro.serving.api import _blocking_join
         return _blocking_join(self, slot, prompt)
 
-    def _join_dense(self, slot: int, prompt) -> np.ndarray:
-        """Dense-KV one-shot admission body: batch=1 prefill, KV scattered
-        into the slot's cache rows.  Called from join_step."""
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        batch = Batch(tokens=jnp.asarray(prompt[None]),
-                      loss_mask=jnp.ones((1, len(prompt)), jnp.float32))
-        logits, cache, positions = self._prefill_fn()(self.params, batch)
-        one = self._flat_decode_cache(cache)
-        for li in range(self.cfg.num_layers):
-            self.kv_cache[li] = jax.tree_util.tree_map(
-                lambda dst, src: dst.at[slot].set(src[0].astype(dst.dtype)),
-                self.kv_cache[li], one[li])
-        self.positions = self.positions.at[slot].set(int(positions[0]))
-        self.active[slot] = True
-        self._pending_preds = [pp for pp in self._pending_preds
-                               if pp[2] != slot]
-        return np.asarray(logits[0], np.float32)
-
     def join_begin(self, slot: int, prompt, reserve_tokens=None):
-        """Start an incremental admission into `slot`.  Paged KV: reserves
-        pages for `reserve_tokens` (default max_len) and queues the prompt
-        for chunked prefill.  Dense KV: stashes the prompt (join_step then
-        runs the one-shot prefill)."""
+        """Start an incremental admission into `slot`: reserves pages for
+        `reserve_tokens` (default max_len) and queues the prompt for
+        chunked prefill."""
         self._check_open()
         prompt = np.asarray(prompt, np.int32).reshape(-1)
-        if self.ecfg.paged_kv:
-            self._admission.begin(slot, prompt,
-                                  reserve_tokens=reserve_tokens or self.max_len)
-        else:
-            self._pending_joins[slot] = prompt
+        self._admission.begin(slot, prompt,
+                              reserve_tokens=reserve_tokens or self.max_len)
 
     def join_step(self) -> Dict[int, np.ndarray]:
         """Advance every in-progress admission one prefill chunk (ONE shared
-        jitted call under paged KV); completed slots become active.  Returns
-        {slot: last-token logits} — including logits another slot's blocking
+        jitted call); completed slots become active.  Returns {slot:
+        last-token logits} — including logits another slot's blocking
         ``join`` finished but did not claim."""
         with self.spans("hobbit.prefill.chunk"):
             done: Dict[int, np.ndarray] = dict(self._unclaimed_joins)
             self._unclaimed_joins = {}
-            if self.ecfg.paged_kv:
-                done.update(self._admission.step())
-                for slot in done:
-                    plen = int(self.kv_pool.lens[slot])
-                    self.positions = self.positions.at[slot].set(plen)
-                    self.active[slot] = True
-                    self._pending_preds = [pp for pp in self._pending_preds
-                                           if pp[2] != slot]
-                return done
-            for slot, prompt in list(self._pending_joins.items()):
-                del self._pending_joins[slot]
-                done[slot] = self._join_dense(slot, prompt)
+            done.update(self._admission.step())
+            for slot in done:
+                plen = int(self.kv_pool.lens[slot])
+                self.positions = self.positions.at[slot].set(plen)
+                self.active[slot] = True
+                self._pending_preds = [pp for pp in self._pending_preds
+                                       if pp[2] != slot]
             return done
 
     def can_admit(self, tokens: int, *, prompt=None) -> bool:
-        """KV-capacity admission gate: paged KV checks unreserved pages
-        (with `prompt`, net of the best prefix-sharing plan — aliased
-        prefix pages cost nothing); dense KV always admits (slots are
-        pre-allocated to max_len)."""
-        if self.ecfg.paged_kv and self.kv_pool is not None:
+        """KV-capacity admission gate: checks unreserved pages (with
+        `prompt`, net of the best prefix-sharing plan — aliased prefix
+        pages cost nothing)."""
+        if self.kv_pool is not None:
             return self.kv_pool.can_reserve(tokens, prompt=prompt)
         return True
 
     def release(self, slot: int):
-        """Free a slot (its KV rows become junk until the next join; paged
-        KV returns the slot's pages to the pool).  Retires the request from
-        the fleet heat map (one decay tick — the cross-request prior ages
-        by requests, not wall clock)."""
+        """Free a slot and return its pages to the pool.  Retires the
+        request from the fleet heat map (one decay tick — the cross-request
+        prior ages by requests, not wall clock)."""
         self.active[slot] = False
         self._pending_preds = [pp for pp in self._pending_preds
                                if pp[2] != slot]
-        if self.ecfg.paged_kv and self.kv_pool is not None:
+        if self.kv_pool is not None:
             self.kv_pool.release(slot)
         self.fleet.retire_request()
 
     def pause(self, slot: int) -> Dict:
-        """Preempt `slot` mid-decode: snapshot its KV state to host, then
-        free the slot (paged KV returns its pages to the pool — the
-        snapshot is taken FIRST, so aliased prefix pages are copied out
-        while the remaining sharers keep the originals and their
-        refcounts).  Returns the opaque snapshot for ``resume``.  The
-        expert cache is untouched: it is shared, and the fleet heat map
-        keeps the victim's experts warm for its return."""
+        """Preempt `slot` mid-decode: snapshot its KV pages to host, then
+        return them to the pool (the snapshot is taken FIRST, so aliased
+        prefix pages are copied out while the remaining sharers keep the
+        originals and their refcounts).  Returns the opaque snapshot for
+        ``resume``.  The expert cache is untouched: it is shared, and the
+        fleet heat map keeps the victim's experts warm for its return."""
         self._check_open()
         pos = int(np.asarray(self.positions)[slot])
         self._pending_preds = [pp for pp in self._pending_preds
                                if pp[2] != slot]
-        if self.ecfg.paged_kv and self.kv_pool is not None:
-            snap = self.kv_pool.snapshot_slot(slot)
-            self.kv_pool.release(slot)
-            self.active[slot] = False
-            return {"layout": "paged", "position": pos, "kv": snap}
-        rows = [{"k": np.asarray(c["k"][slot]), "v": np.asarray(c["v"][slot])}
-                for c in self.kv_cache]
+        snap = self.kv_pool.snapshot_slot(slot)
+        self.kv_pool.release(slot)
         self.active[slot] = False
-        return {"layout": "dense", "position": pos, "cache": rows}
+        return {"position": pos, "kv": snap}
 
     def resume(self, slot: int, snapshot: Dict) -> None:
         """Reinstate a paused request into (a possibly different) `slot`
         from its ``pause`` snapshot; decode continues logits-identically.
-        Paged KV raises PagePoolExhausted when the pool cannot host the
-        snapshot right now (the scheduler keeps it and retries)."""
+        Raises PagePoolExhausted when the pool cannot host the snapshot
+        right now (the scheduler keeps it and retries)."""
         self._check_open()
-        if snapshot["layout"] == "paged":
-            self.kv_pool.restore_slot(slot, snapshot["kv"])
-        else:
-            for li, row in enumerate(snapshot["cache"]):
-                c = self.kv_cache[li]
-                self.kv_cache[li] = {
-                    "k": c["k"].at[slot].set(jnp.asarray(row["k"])),
-                    "v": c["v"].at[slot].set(jnp.asarray(row["v"]))}
+        self.kv_pool.restore_slot(slot, snapshot["kv"])
         self.positions = self.positions.at[slot].set(int(snapshot["position"]))
         self.active[slot] = True
 
@@ -761,13 +659,13 @@ class OffloadEngine:
 
         Two implementations share this contract: the grouped path (default —
         one batched gating matmul, one hi GEMM and one lo dequant-GEMM per
-        MoE layer, async double-buffered prefetch) and the per-expert
-        reference path (``grouped=False`` or host compute mode), kept as the
+        MoE layer, prefetch staged by StagingEngine) and the per-expert
+        reference path (``grouped=False``, synchronous loading), kept as the
         numerics baseline the parity tests compare against."""
         self._check_open()
         self._steps += 1
         with self.spans.step("hobbit.decode", self._steps):
-            if self.ecfg.grouped and self.ecfg.compute_mode == "device":
+            if self.ecfg.grouped:
                 return self._decode_step_batch_grouped(tokens)
             return self._decode_step_batch_reference(tokens)
 
@@ -873,10 +771,8 @@ class OffloadEngine:
         combine_fn = self._jit("residual_add",
                                lambda xx, yy: xx + yy.astype(xx.dtype))
 
-        table = active_dev = None
-        if ecfg.paged_kv:
-            with sp("hobbit.decode.prologue"):
-                table, active_dev = self._paged_step_prologue(rows)
+        with sp("hobbit.decode.prologue"):
+            table, active_dev = self._paged_step_prologue(rows)
         row_trace = {r: [] for r in rows}
         for mi, li in enumerate(self.moe_layers):
             with sp("hobbit.layer.attn", layer=mi):
@@ -887,7 +783,7 @@ class OffloadEngine:
                 self.scheduler.set_deadline_clock(mi, self._layer_s_ema,
                                                   self._layer_period_ema)
                 p = self.layer_params[li]
-                x = self._attn_layer(li, x, table=table, active_dev=active_dev)
+                x = self._attn_layer(li, x, table, active_dev)
                 h = ffn_in(p, x)                               # (B,1,D)
                 # forcing h here keeps the pending attn/ffn-in compute out
                 # of the gate span
@@ -924,21 +820,17 @@ class OffloadEngine:
                 pred_entry = {}
                 if ecfg.prefetch:
                     pred_entry = self._prefetch_predictions(
-                        mi, rows, h_host, use_async=ecfg.async_prefetch)
+                        mi, rows, h_host, use_async=True)
 
             # ---- loading ----
             with sp("hobbit.layer.stage_wait", layer=mi):
                 t_load0 = time.perf_counter()
-                if ecfg.async_prefetch:
-                    # barrier: land every prefetch targeting this layer
-                    # (copies have been staging in the background since they
-                    # were predicted), then blocking-load the residual miss
-                    # set in one batched transfer
-                    self.scheduler.wait(mi)
-                    self.scheduler.drain_on_demand(self.loader.take_queued(),
-                                                   mi)
-                else:
-                    self.loader.drain(mi)
+                # barrier: land every prefetch targeting this layer (copies
+                # have been staging in the background since they were
+                # predicted), then blocking-load the residual miss set in
+                # one batched transfer
+                self.scheduler.wait(mi)
+                self.scheduler.drain_on_demand(self.loader.take_queued(), mi)
                 t_load = time.perf_counter() - t_load0
                 # a commit's upload holds device memory until its scatter
                 # has run: land them all before grouped_ffn is enqueued, or
@@ -1046,7 +938,7 @@ class OffloadEngine:
                     # still landing (wait() never blocks on upgrades); the
                     # slot holds no hi weights yet
                     slot = None
-                if (slot is None and is_hi and ecfg.async_prefetch
+                if (slot is None and is_hi
                         and self.scheduler.serves_lo_downgrade(mi, e)):
                     # issue-time precision downgrade: the staging engine
                     # replaced this hi copy with a lo one under link
@@ -1102,13 +994,11 @@ class OffloadEngine:
         hi_exp = self._jit("hi", self._hi_expert)
         lo_exp = self._jit("lo", self._lo_expert)
 
-        table = active_dev = None
-        if ecfg.paged_kv:
-            table, active_dev = self._paged_step_prologue(rows)
+        table, active_dev = self._paged_step_prologue(rows)
         row_trace = {r: [] for r in rows}
         for mi, li in enumerate(self.moe_layers):
             p = self.layer_params[li]
-            x = self._attn_layer(li, x, table=table, active_dev=active_dev)
+            x = self._attn_layer(li, x, table, active_dev)
             h = ffn_in(p, x)                                   # (B,1,D)
             h_host = self._to_host(h[:, 0], np.float32)        # (B,D)
 
@@ -1160,15 +1050,11 @@ class OffloadEngine:
                         else:
                             self.cache.stats.misses_lo += 1
                         slot, _ = self.cache.admit((mi, int(e)), is_hi, mi)
-                        self._fetch(mi, int(e), int(d_), slot)
+                        self._write_expert(mi, int(e), int(d_), slot)
                         self.loader.loaded_bytes += self.expert_bytes[int(d_)]
                         self.loader.n_loads[int(d_)] += 1
                         self._union_reloads += 1
-                    if self.ecfg.compute_mode == "host":
-                        out = self._host_expert(mi, int(e), d_,
-                                                self._to_host(hr, np.float32))
-                        out = jnp.asarray(out, hr.dtype)
-                    elif is_hi:
+                    if is_hi:
                         out = hi_exp(self.pool_hi["wi"][slot],
                                      self.pool_hi["wo"][slot], hr)
                     else:
@@ -1213,26 +1099,6 @@ class OffloadEngine:
         """One HOBBIT decode step (batch=1 legacy API).  Returns logits (V,)."""
         assert self.batch == 1, "decode_token is batch=1; use decode_step_batch"
         return self.decode_step_batch(np.asarray([int(token)], np.int32))[0]
-
-    def _host_expert(self, mi, e, d_, h):
-        """CPU-GPU cooperative mode (§4): run the expert on host weights."""
-        cfg = self.cfg
-        if d_ == PREC_HI:
-            # numpy math in bfloat16 is slow and rounds per op: upcast
-            wi = np.asarray(self.storage_hi[mi]["wi"][e], np.float32)
-            wo = np.asarray(self.storage_hi[mi]["wo"][e], np.float32)
-        else:
-            wi = self._to_host(dequantize(jax.tree_util.tree_map(
-                lambda a: a[e], self.storage_lo[mi]["wi"])))
-            wo = self._to_host(dequantize(jax.tree_util.tree_map(
-                lambda a: a[e], self.storage_lo[mi]["wo"])))
-        z = h @ wi
-        if cfg.ffn_activation == "swiglu":
-            g, u = np.split(z, 2, axis=-1)
-            z = (g / (1 + np.exp(-g))) * u
-        else:
-            z = 0.5 * z * (1 + np.tanh(np.sqrt(2 / np.pi) * (z + 0.044715 * z**3)))
-        return z @ wo
 
     # ------------------------------------------------------------------
     # evaluation helpers
@@ -1286,7 +1152,7 @@ class OffloadEngine:
             # showing only .xla counts is silently benchmarking the einsum
             # oracle path
             "kernel_dispatch": kops.dispatch_counts(),
-            # KV page-pool pressure (zeros under the dense KV layout)
+            # KV page-pool pressure (zeros before the first start_batch)
             "kv_pages_used": 0, "kv_pages_total": 0, "kv_page_fraction": 0.0,
             # {span name: {"s": seconds, "n": count}} (core/spans.py)
             "spans": self.spans.totals(),
@@ -1297,7 +1163,7 @@ class OffloadEngine:
             "served_lo": self._served[PREC_LO],
             "served_skip": self._served[PREC_SKIP],
         }
-        if self.ecfg.paged_kv and self.kv_pool is not None:
+        if self.kv_pool is not None:
             s.update(self.kv_pool.stats())
         s.update(self.scheduler.stats())
         return s

@@ -71,9 +71,11 @@ def main():
                     help="jit the dense prefill/decode steps "
                          "(--no-jit: eager, for debugging)")
     ap.add_argument("--paged-kv", action="store_true",
-                    help="paged KV cache: slots draw pages from a shared "
-                         "pool instead of each allocating max_len up front "
-                         "(either backend; all-'attn' archs)")
+                    help="paged KV cache for --backend dense: slots draw "
+                         "pages from a shared pool instead of each "
+                         "allocating max_len up front (all-'attn' archs); "
+                         "--backend hobbit always pages, and this flag "
+                         "hands it the page flags below")
     ap.add_argument("--page-size", type=int, default=64,
                     help="tokens per KV page (--paged-kv)")
     ap.add_argument("--kv-pages", type=int, default=None,
@@ -177,7 +179,7 @@ def main():
         if kind == "hobbit" else None), model, params)
 
     rng = np.random.default_rng(0)
-    report = {"backend": kind, "paged_kv": args.paged_kv}
+    report = {"backend": kind, "paged_kv": args.paged_kv or kind == "hobbit"}
 
     if args.serve_requests > 0:
         srv = BatchingServer(backend, max_batch=args.max_batch,

@@ -29,11 +29,13 @@ one typed config instead of the historical kwarg sprawl (the old
 ``make_backend(kind, ..., paged=..., page_size=...)`` form still works for
 one release behind a DeprecationWarning).
 
-KV memory comes in two layouts, selected per backend at construction:
+KV memory comes in two layouts for `DenseBackend`, selected at
+construction; `HobbitBackend` always uses the paged one:
 
-  * dense (default): ``start_batch`` allocates a (B, max_len) cache up
-    front — simple, but one long request inflates every slot.
-  * paged (``paged=True`` / ``EngineConfig(paged_kv=True)``): a fixed
+  * dense (``DenseBackend`` default): ``start_batch`` allocates a
+    (B, max_len) cache up front — simple, but one long request inflates
+    every slot.
+  * paged (``DenseBackend(paged=True)``, and the offload engine): a fixed
     device-resident page pool (`repro.models.kv_pages.PagedKVPool`,
     ~64-token pages) with per-slot page tables; a slot's memory grows with
     its actual length, ``release`` returns its pages to the pool, and
@@ -362,10 +364,12 @@ class DenseBackend:
             return done
         for slot, prompt in list(self._pending_joins.items()):
             del self._pending_joins[slot]
-            done[slot] = self._join_dense(slot, prompt)
+            done[slot] = self._join_oneshot(slot, prompt)
         return done
 
-    def _join_dense(self, slot: int, prompt) -> np.ndarray:
+    def _join_oneshot(self, slot: int, prompt) -> np.ndarray:
+        """Unpaged admission: one batch=1 prefill, its cache scattered into
+        the slot's rows."""
         prompt = jnp.asarray(np.asarray(prompt, np.int32).reshape(1, -1))
         batch = Batch(tokens=prompt, loss_mask=jnp.ones(prompt.shape))
         logits, one_cache, positions = self._prefill(self.max_len)(
@@ -472,22 +476,22 @@ class DenseBackend:
 class HobbitBackend:
     """`OffloadEngine` behind the protocol: batched mixed-precision decode
     with union-of-slots expert loading and a real (dense, compute-bound)
-    prefill path.  `EngineConfig(paged_kv=True)` selects the paged KV
-    layout; the engine then shares the same `PagedKVPool` / `ChunkedPrefill`
-    machinery as `DenseBackend`."""
+    chunked prefill.  Its KV is always paged, through the same
+    `PagedKVPool` / `ChunkedPrefill` machinery as `DenseBackend(paged=True)`
+    (page settings in `EngineConfig`)."""
 
     def __init__(self, engine):
         self.engine = engine
         self.model = engine.model
 
     def start_batch(self, batch: int, max_len: int) -> None:
-        """Allocate engine serving state (dense per-layer caches or the
-        page pool) for `batch` slots."""
+        """Allocate engine serving state (the KV page pool) for `batch`
+        slots."""
         self.engine.start_batch(batch, max_len)
 
     def prefill(self, prompts) -> np.ndarray:
-        """Full-batch dense-compute prefill (prefill touches every expert
-        anyway; the offload cache only serves decode)."""
+        """Full-batch chunked prefill with dense compute (prefill touches
+        every expert anyway; the offload cache only serves decode)."""
         return self.engine.prefill_batch(prompts)
 
     def join(self, slot: int, prompt) -> np.ndarray:
@@ -498,7 +502,7 @@ class HobbitBackend:
 
     def join_begin(self, slot: int, prompt,
                    reserve_tokens: Optional[int] = None) -> None:
-        """Start an incremental admission (chunked under paged KV)."""
+        """Start an incremental (chunked) admission."""
         self.engine.join_begin(slot, prompt, reserve_tokens=reserve_tokens)
 
     def join_step(self) -> dict:
@@ -506,14 +510,14 @@ class HobbitBackend:
         return self.engine.join_step()
 
     def can_admit(self, tokens: int, *, prompt=None) -> bool:
-        """KV-capacity gate for admission (always True under dense KV; with
-        `prompt`, paged engines price the request net of prefix sharing)."""
+        """KV-capacity gate for admission (with `prompt`, the request is
+        priced net of prefix sharing)."""
         return self.engine.can_admit(tokens, prompt=prompt)
 
     def pause(self, slot: int) -> dict:
-        """Preempt `slot` mid-decode: snapshot its KV (dense rows or paged
-        pages, prefix-sharing refcounts handled by the pool) to host and
-        free the slot for a more urgent request."""
+        """Preempt `slot` mid-decode: snapshot its KV pages (prefix-sharing
+        refcounts handled by the pool) to host and free the slot for a more
+        urgent request."""
         return self.engine.pause(slot)
 
     def resume(self, slot: int, snapshot: dict) -> None:
@@ -522,7 +526,7 @@ class HobbitBackend:
         self.engine.resume(slot, snapshot)
 
     def release(self, slot: int) -> None:
-        """Free a slot (and its KV pages under paged KV)."""
+        """Free a slot and its KV pages."""
         self.engine.release(slot)
 
     def step(self, tokens) -> np.ndarray:
@@ -549,13 +553,15 @@ class BackendConfig:
 
     kind            "dense" (resident weights) or "hobbit" (offload engine)
     jit             jit the dense prefill/decode steps
-    paged           paged KV layout (shared page pool) on either backend
+    paged           paged KV layout (shared page pool) for kind="dense"; the
+                    offload engine always pages, and `paged=True` hands it
+                    the four page fields below
     page_size       tokens per KV page
     kv_pages        pool size in pages (None: the dense equivalent)
     prefill_chunk   tokens per chunked-prefill call
     prefix_sharing  radix prefix cache over the paged pool
     engine          `core.EngineConfig` for kind="hobbit" (None: defaults);
-                    `paged=True` overrides its paged-KV fields
+                    `paged=True` overrides its page fields
     """
     kind: str = "dense"
     jit: bool = True
@@ -606,8 +612,7 @@ def make_backend(kind, model: Model, params, *, engine_config=_UNSET,
         from repro.core.engine import EngineConfig, OffloadEngine
         ecfg = cfg.engine or EngineConfig()
         if cfg.paged:
-            ecfg = dataclasses.replace(ecfg, paged_kv=True,
-                                       kv_page_size=cfg.page_size,
+            ecfg = dataclasses.replace(ecfg, kv_page_size=cfg.page_size,
                                        kv_pages=cfg.kv_pages,
                                        prefill_chunk=cfg.prefill_chunk,
                                        prefix_sharing=cfg.prefix_sharing)

@@ -1,5 +1,6 @@
 """OffloadEngine integration tests: numerics vs the reference decode path,
-precision-substitution effects, cooperative (host) mode, stats coherence."""
+precision-substitution effects, stats coherence, and the paged-KV model
+class the engine serves."""
 
 import dataclasses
 
@@ -11,6 +12,7 @@ import pytest
 from repro.configs import get_config, smoke_variant
 from repro.core import EngineConfig, OffloadEngine, Thresholds
 from repro.models import build_model
+from repro.models.model import supports_paged_kv
 
 
 @pytest.fixture(scope="module")
@@ -81,16 +83,6 @@ def test_skip_degrades_more_than_replace(setup):
     assert abs(rep.score_nll(toks) - nb) <= abs(skp.score_nll(toks) - nb) + 1e-6
 
 
-def test_host_compute_mode_matches_device(setup):
-    m, params = setup
-    toks = [3, 8, 1, 4]
-    kw = dict(hi_slots=32, lo_slots=8, thresholds=Thresholds(1.0, 1.0),
-              prefetch=False)
-    dev = OffloadEngine(m, params, EngineConfig(**kw))
-    host = OffloadEngine(m, params, EngineConfig(compute_mode="host", **kw))
-    assert abs(dev.score_nll(toks) - host.score_nll(toks)) < 1e-3
-
-
 def test_engine_stats_consistent(setup):
     m, params = setup
     eng = OffloadEngine(m, params, EngineConfig(hi_slots=8, lo_slots=4))
@@ -119,3 +111,14 @@ def test_engine_small_cache_thrashes_but_stays_correct(setup):
     want = _reference_nll(m, params, toks)
     assert abs(tiny.score_nll(toks) - want) < 1e-4
     assert tiny.cache.stats.hit_ratio() < 0.6   # lots of misses with 2 slots
+
+
+def test_engine_refuses_model_without_paged_kv():
+    """The engine serves paged KV only: a MoE model whose attention the page
+    pool cannot hold (DeepSeek-V2's latent MLA cache) is refused when the
+    engine is built, not at its first start_batch."""
+    cfg = smoke_variant(get_config("deepseek-v2-236b"))
+    m = build_model(cfg)
+    assert cfg.moe is not None and not supports_paged_kv(cfg)
+    with pytest.raises(ValueError, match="paged KV"):
+        OffloadEngine(m, m.init(jax.random.PRNGKey(0)), EngineConfig())

@@ -1,7 +1,8 @@
 """Grouped-decode + async-loading tests: parity of the grouped path against
 the per-expert reference path, O(1) expert-compute dispatches, async
 double-buffered prefetch (wall-clock overlap accounting, in-flight
-reservation safety), deduplicated pending-prediction bookkeeping, and the
+reservation safety), one device write of an expert for the synchronous and
+staged loaders, deduplicated pending-prediction bookkeeping, and the
 union-overflow / all-hard-pinned cache corners at batch > 1."""
 
 import dataclasses
@@ -14,7 +15,8 @@ import pytest
 from repro.configs import get_config, smoke_variant
 from repro.core import (CacheStarvation, EngineConfig, LRU,
                         MultidimensionalCache, OffloadEngine, PREC_HI,
-                        Thresholds)
+                        PREC_LO, Thresholds)
+from repro.core.loader import ON_DEMAND, LoadTask
 from repro.models import build_model
 from repro.serving.api import HobbitBackend, generate
 
@@ -33,8 +35,9 @@ def setup():
 
 
 def _reference(ecfg: EngineConfig) -> EngineConfig:
-    """Same engine settings on the per-expert reference path."""
-    return dataclasses.replace(ecfg, grouped=False, async_prefetch=False)
+    """Same engine settings on the per-expert reference path (which loads
+    synchronously)."""
+    return dataclasses.replace(ecfg, grouped=False)
 
 
 def _step_logits(m, params, ecfg, prompts, teacher):
@@ -116,18 +119,19 @@ def test_grouped_issues_one_dispatch_per_layer(setup):
 
 # ------------------------------------------------ async prefetch overlap
 def test_async_prefetch_matches_sync_and_reports_overlap(setup):
+    """The grouped path's staged prefetch yields the tokens of the
+    per-expert reference, which loads synchronously, and reports its
+    overlap."""
     m, params = setup
     ecfg = EngineConfig(hi_slots=8, lo_slots=4)
     prompts = np.random.default_rng(14).integers(0, 256, (2, 5))
-    res_async = generate(HobbitBackend(OffloadEngine(m, params, ecfg)),
-                         prompts, 6, max_len=32)
-    sync = dataclasses.replace(ecfg, async_prefetch=False)
-    res_sync = generate(HobbitBackend(OffloadEngine(m, params, sync)),
-                        prompts, 6, max_len=32)
+    eng = OffloadEngine(m, params, ecfg)
+    res_async = generate(HobbitBackend(eng), prompts, 6, max_len=32)
+    res_sync = generate(
+        HobbitBackend(OffloadEngine(m, params, _reference(ecfg))),
+        prompts, 6, max_len=32)
     np.testing.assert_array_equal(res_async.tokens, res_sync.tokens)
 
-    eng = OffloadEngine(m, params, ecfg)
-    generate(HobbitBackend(eng), prompts, 6, max_len=32)
     s = eng.stats()
     assert s["prefetch_jobs"] > 0              # async staging actually ran
     assert 0.0 <= s["overlap_fraction"] <= 1.0
@@ -151,6 +155,42 @@ def test_fetch_many_writes_all_slots(setup):
     for _, e, _, slot in items:
         np.testing.assert_allclose(np.asarray(eng.pool_hi["wi"][slot]),
                                    eng.storage_hi[0]["wi"][e], rtol=1e-6)
+
+
+def test_sync_drain_writes_like_a_staged_commit(setup):
+    """The synchronous loader's drain and the staging engine's on-demand
+    commit write an expert the same way: the same slot holds the same bits
+    in every pool tensor, and both add the same commit_shipped_bytes (the
+    expert's host bytes) under one hobbit.stage.commit span."""
+    m, params = setup
+    ecfg = EngineConfig(hi_slots=8, lo_slots=4)
+    for prec, e in ((PREC_HI, 3), (PREC_LO, 2)):
+        pools, shipped = [], []
+        for staged in (False, True):
+            eng = OffloadEngine(m, params, ecfg)
+            eng.start_batch(1, 8)
+            s0 = eng.stats()
+            task = LoadTask(1, e, prec, ON_DEMAND, eng.expert_bytes[prec])
+            if staged:
+                done = eng.scheduler.drain_on_demand([task], 1)
+            else:
+                eng.loader.queue.append(task)
+                done = eng.loader.drain(1)
+            s1 = eng.stats()
+            [(_, slot)] = done
+            pool = eng.pool_hi if prec == PREC_HI else eng.pool_lo
+            pools.append((slot, {k: np.asarray(a[slot]).tobytes()
+                                 for k, a in pool.items()}))
+            shipped.append(s1["commit_shipped_bytes"]
+                           - s0["commit_shipped_bytes"])
+            commits = s1["spans"]["hobbit.stage.commit"]["n"] - s0["spans"][
+                "hobbit.stage.commit"]["n"]
+            assert commits == 1, (prec, staged)
+            host = eng._stage(1, e, prec)
+            assert shipped[-1] == sum(a.nbytes for a in host.values())
+            assert pools[-1][1] == {k: a.tobytes() for k, a in host.items()}
+        assert pools[0] == pools[1], prec
+        assert shipped[0] == shipped[1], prec
 
 
 def test_async_scheduler_commits_staged_weights(setup):

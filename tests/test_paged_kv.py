@@ -1,5 +1,6 @@
-"""Paged KV cache + chunked prefill admission tests: paged-vs-dense logits
-parity through both backends, chunked-prefill equivalence to one-shot
+"""Paged KV cache + chunked prefill admission tests: logits parity of both
+backends' paged layout against the dense model's unpaged run, chunked-
+prefill equivalence to one-shot
 prefill, page reclamation on mid-flight release with immediate re-admission,
 pool-exhaustion behavior (queued request waits, never crashes), and the new
 stats fields (kv_pages_used / kv_page_fraction / admission_wait_s)."""
@@ -31,15 +32,23 @@ def setup():
     return m, m.init(jax.random.PRNGKey(0))
 
 
-def _mk(kind, m, params, *, paged, **kw):
+def _mk(kind, m, params, *, paged, exact=False, **kw):
+    """A backend of `kind`.  The offload engine always pages: for it
+    `paged=False` means its default page settings and `paged=True` hands it
+    the page settings in `kw`.  `exact` holds every expert at full
+    precision, so the engine's numerics are the dense model's."""
     if kind == "dense":
         return DenseBackend(m, params, paged=paged, **kw)
-    ecfg = EngineConfig(hi_slots=16, lo_slots=8,
-                        thresholds=Thresholds(0.6, 0.9))
+    if exact:
+        n = m.cfg.num_layers * m.cfg.moe.num_experts
+        ecfg = EngineConfig(hi_slots=n, lo_slots=1,
+                            thresholds=Thresholds(1.0, 1.0), prefetch=False)
+    else:
+        ecfg = EngineConfig(hi_slots=16, lo_slots=8,
+                            thresholds=Thresholds(0.6, 0.9))
     if paged:
         ecfg = dataclasses.replace(
-            ecfg, paged_kv=True,
-            kv_page_size=kw.get("page_size", 64),
+            ecfg, kv_page_size=kw.get("page_size", 64),
             kv_pages=kw.get("kv_pages"),
             prefill_chunk=kw.get("prefill_chunk", 64))
     return HobbitBackend(OffloadEngine(m, params, ecfg))
@@ -48,13 +57,16 @@ def _mk(kind, m, params, *, paged, **kw):
 # ---------------------------------------------------------------- parity
 @pytest.mark.parametrize("kind", ["dense", "hobbit"])
 def test_paged_vs_dense_logits_parity(setup, kind):
-    """Per-step decode logits under the paged layout equal the dense-layout
-    run on both backends (page size chosen so slots span several pages)."""
+    """Per-step decode logits of either backend under the paged layout
+    equal the dense model's unpaged run (page size chosen so slots span
+    several pages; the offload engine holds every expert at full
+    precision)."""
     m, params = setup
     prompts = np.random.default_rng(0).integers(0, 128, (2, 9))
     teacher = np.random.default_rng(1).integers(0, 128, (4, 2))
-    d = _mk(kind, m, params, paged=False)
-    p = _mk(kind, m, params, paged=True, page_size=4, prefill_chunk=5)
+    d = DenseBackend(m, params, paged=False)
+    p = _mk(kind, m, params, paged=True, exact=True, page_size=4,
+            prefill_chunk=5)
     d.start_batch(2, 32)
     p.start_batch(2, 32)
     lg_d, lg_p = d.prefill(prompts), p.prefill(prompts)
@@ -68,9 +80,9 @@ def test_paged_vs_dense_logits_parity(setup, kind):
 def test_paged_generate_tokens_equal(setup, kind):
     m, params = setup
     prompts = np.random.default_rng(2).integers(0, 128, (2, 7))
-    res_d = generate(_mk(kind, m, params, paged=False), prompts, 6)
-    res_p = generate(_mk(kind, m, params, paged=True, page_size=4,
-                         prefill_chunk=3), prompts, 6)
+    res_d = generate(DenseBackend(m, params, paged=False), prompts, 6)
+    res_p = generate(_mk(kind, m, params, paged=True, exact=True,
+                         page_size=4, prefill_chunk=3), prompts, 6)
     np.testing.assert_array_equal(res_d.tokens, res_p.tokens)
 
 

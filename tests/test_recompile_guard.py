@@ -6,8 +6,8 @@ retraces: one silent recompile costs more than a hundred steps.  These
 tests warm a backend up, snapshot every jitted callable's compiled-variant
 count (`_cache_size()`),
 run more decode steps at identical shapes, and require the counts to be
-bit-identical — for the grouped, paged-KV and multi-stream-staged engine
-configurations plus the paged dense backend.
+bit-identical — for the single-stream, small-page and multi-stream-staged
+engine configurations plus the paged dense backend.
 """
 
 import dataclasses
@@ -26,15 +26,16 @@ from repro.serving.decode import sample_token
 WARMUP, STEADY = 8, 8
 
 ENGINE_CONFIGS = {
-    # grouped batched dispatch, synchronous staging: isolates the grouped
-    # decode jits (one gating matmul + hi GEMM + lo dequant-GEMM per layer)
+    # grouped batched dispatch, single-stream FIFO staging: isolates the
+    # grouped decode jits (one gating matmul + hi GEMM + lo dequant-GEMM per
+    # layer)
     "grouped": dict(hi_slots=8, lo_slots=4, grouped=True, streams=1,
-                    ordered=True, async_prefetch=False),
-    # paged KV: decode runs through attn_paged over the shared page pool
-    "paged": dict(hi_slots=8, lo_slots=4, paged_kv=True, kv_page_size=4,
-                  kv_pages=32),
+                    ordered=True),
+    # small pages: decode crosses page boundaries through attn_paged over
+    # the shared page pool
+    "paged": dict(hi_slots=8, lo_slots=4, kv_page_size=4, kv_pages=32),
     # multi-stream byte-budgeted staging riding alongside decode
-    "staged": dict(hi_slots=8, lo_slots=4, streams=2, async_prefetch=True),
+    "staged": dict(hi_slots=8, lo_slots=4, streams=2),
 }
 
 
@@ -90,8 +91,7 @@ def test_kernel_tier_decode_steady_state_zero_recompiles(setup, monkeypatch):
     monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
     m, params = setup
     eng = OffloadEngine(m, params, EngineConfig(
-        hi_slots=8, lo_slots=4, grouped=True, paged_kv=True, kv_page_size=4,
-        kv_pages=32))
+        hi_slots=8, lo_slots=4, grouped=True, kv_page_size=4, kv_pages=32))
     be = HobbitBackend(eng)
     try:
         before, after = _drive(be, lambda: dict(eng._jit_cache))

@@ -90,7 +90,7 @@ def test_ordered_single_stream_matches_reference_and_default(setup):
         return generate(HobbitBackend(OffloadEngine(m, params, ecfg)),
                         prompts, 6, max_len=32).tokens
 
-    t_ref = toks(EngineConfig(grouped=False, async_prefetch=False, **base))
+    t_ref = toks(EngineConfig(grouped=False, **base))
     t_fifo = toks(EngineConfig(streams=1, ordered=True, **base))
     t_budg = toks(EngineConfig(**base))     # default: streams=2, budgeted
     np.testing.assert_array_equal(t_fifo, t_ref)

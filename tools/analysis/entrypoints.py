@@ -128,8 +128,8 @@ def _smoke() -> Tuple[Any, Any, Any]:
     m = build_model(cfg)
     params = m.init(jax.random.PRNGKey(0))
     eng = OffloadEngine(m, params, EngineConfig(
-        hi_slots=8, lo_slots=4, grouped=True, paged_kv=True, kv_page_size=4,
-        kv_pages=32, link_gbps=8.0))
+        hi_slots=8, lo_slots=4, grouped=True, kv_page_size=4, kv_pages=32,
+        link_gbps=8.0))
     eng.start_batch(2, 24)
     return m, params, eng
 
