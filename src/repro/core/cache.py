@@ -32,6 +32,11 @@ Lifecycle hooks: `new_sequence()` resets records and pins at batch
 boundaries; `advance_token()` clears pins each decode step.  See
 docs/ARCHITECTURE.md for where this sits in the decode loop and
 core/loader.py for the scheduler half of the handshake.
+
+Prefetch use: an entry admitted with ``admit(..., prefetch=True)`` stays
+*unprobed* until a `probe()` (or `note_use()`) finds its slot, in flight or
+landed.  One that leaves its slot first — evicted, cancelled, or still
+unprobed at `new_sequence()` — adds one to `prefetch_unused`.
 """
 
 from __future__ import annotations
@@ -136,6 +141,10 @@ class MultidimensionalCache:
         # reassigned slot) and compute must wait() before reading the slot.
         self.inflight: Dict[Tuple[ExpertKey, bool], int] = {}
         self.stats = CacheStats()          # owner: main-thread
+        # prefetched (key, is_hi) entries no probe has found yet, and the
+        # count of those that left their slot before one did
+        self.unprobed_prefetch: Set[Tuple[ExpertKey, bool]] = set()
+        self.prefetch_unused = 0           # owner: main-thread
 
     # ------------- sequence / token lifecycle -------------
     # owner: main-thread
@@ -143,6 +152,9 @@ class MultidimensionalCache:
         self.records.reset()
         self.pinned.clear()
         self.hard_pinned.clear()
+        # the sequence they were predicted for is over
+        self.prefetch_unused += len(self.unprobed_prefetch)
+        self.unprobed_prefetch.clear()
 
     # owner: main-thread
     def advance_token(self):
@@ -185,6 +197,7 @@ class MultidimensionalCache:
         if pool.lookup(key) == slot:
             pool.remove(key)
             pool.free.append(slot)
+            self._left_slot(key, high_precision)
         # a cancelled entry no longer exists — stale pins for it must not
         # keep constraining _select_victim until the next advance_token
         self.pinned.discard((key, high_precision))
@@ -251,6 +264,8 @@ class MultidimensionalCache:
               count_stats: bool = True) -> Optional[int]:
         """lookup + stats + usage record update on hit."""
         slot = self.lookup(key, high_precision)
+        if slot is not None:
+            self.note_use(key, high_precision)
         if count_stats:
             if slot is not None:
                 if high_precision:
@@ -271,13 +286,27 @@ class MultidimensionalCache:
             self.records.on_use(key, high_precision)
         return slot
 
+    # owner: main-thread
+    def note_use(self, key: ExpertKey, high_precision: bool):
+        """The compute path found this entry's slot: a prefetched copy has
+        been used."""
+        self.unprobed_prefetch.discard((key, high_precision))
+
+    def _left_slot(self, key: ExpertKey, high_precision: bool):
+        """Count a prefetched copy that leaves its slot unprobed."""
+        if (key, high_precision) in self.unprobed_prefetch:
+            self.unprobed_prefetch.discard((key, high_precision))
+            self.prefetch_unused += 1
+
     # ------------- admission / eviction -------------
     # owner: main-thread
     def admit(self, key: ExpertKey, high_precision: bool,
-              current_layer: int) -> Tuple[int, Optional[ExpertKey]]:
+              current_layer: int, *,
+              prefetch: bool = False) -> Tuple[int, Optional[ExpertKey]]:
         """Assign a slot for `key` (evicting the lowest-priority unpinned
         resident if full).  Returns (slot, evicted_key_or_None).  The caller
-        must then write the weights into the returned slot."""
+        must then write the weights into the returned slot.  ``prefetch``
+        marks a copy admitted ahead of its layer, whose use is tracked."""
         pool = self.hi if high_precision else self.lo
         existing = pool.lookup(key)
         if existing is not None:
@@ -291,7 +320,10 @@ class MultidimensionalCache:
             slot = pool.remove(victim)
             evicted = victim
             self.stats.evictions += 1
+            self._left_slot(victim, high_precision)
         pool.insert(key, slot)
+        if prefetch:
+            self.unprobed_prefetch.add((key, high_precision))
         self.records.on_use(key, high_precision)
         return slot, evicted
 

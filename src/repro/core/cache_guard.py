@@ -35,7 +35,7 @@ from repro.core.cache import MultidimensionalCache
 # at runtime)
 GUARDED_METHODS = (
     "new_sequence", "advance_token", "pin", "begin_inflight", "end_inflight",
-    "cancel_inflight", "probe", "admit",
+    "cancel_inflight", "probe", "note_use", "admit",
 )
 
 _LOG_BOUND = 256

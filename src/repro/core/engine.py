@@ -807,20 +807,13 @@ class OffloadEngine:
                 self._score_pending_preds(mi, tops)
 
                 # ---- on-demand scoring (union over slots) ----
-                # Hard-pin this layer's experts BEFORE prefetch admission:
-                # async submit_prefetch admits (and may evict) at submit
-                # time, so without the pins it could evict a resident
-                # expert this very layer is about to compute with.
+                # hard-pin this layer's experts: neither its misses'
+                # admissions nor the prefetch after them may evict one
+                # this layer computes with
                 self.loader.new_layer()
                 for r in rows:
                     self.loader.score_and_enqueue(mi, tops[r].tolist(),
                                                   gates[r], clear_pins=False)
-
-            with sp("hobbit.layer.prefetch", layer=mi):
-                pred_entry = {}
-                if ecfg.prefetch:
-                    pred_entry = self._prefetch_predictions(
-                        mi, rows, h_host, use_async=True)
 
             # ---- loading ----
             with sp("hobbit.layer.stage_wait", layer=mi):
@@ -836,6 +829,16 @@ class OffloadEngine:
                 # has run: land them all before grouped_ffn is enqueued, or
                 # they sit beside its temporaries and raise peak memory
                 jax.block_until_ready((self.pool_hi, self.pool_lo))
+
+            # after the drain: this layer's misses hold their slots, so a
+            # prefetch that finds no free or evictable slot is dropped
+            # rather than evicting one, and its copy commits at the barrier
+            # of the layer that reads it
+            with sp("hobbit.layer.prefetch", layer=mi):
+                pred_entry = {}
+                if ecfg.prefetch:
+                    pred_entry = self._prefetch_predictions(
+                        mi, rows, h_host, use_async=True)
 
             # ---- grouped expert compute: 1 hi + 1 lo dispatch ----
             with sp("hobbit.layer.plan", layer=mi):
@@ -946,6 +949,7 @@ class OffloadEngine:
                     # upgrade lands the hi copy
                     is_hi = False
                     slot = self.cache.lookup((mi, e), False)
+                    self.cache.note_use((mi, e), False)
                     self.scheduler.served_lo_expert_steps += 1
                 if slot is None:
                     # staged in the precision served: a downgraded hi pair
@@ -1147,6 +1151,8 @@ class OffloadEngine:
             "gating_s": self.spans.seconds("hobbit.layer.gate"),
             "expert_dispatches": self._expert_dispatches,
             "union_reloads": self._union_reloads,
+            # prefetched copies that left their slot before a probe found it
+            "prefetch_unused": self.cache.prefetch_unused,
             # which kernel implementation each hot-path op dispatched/traced
             # ("<op>.<xla|pallas|pallas_interpret>" -> count): a TPU run
             # showing only .xla counts is silently benchmarking the einsum

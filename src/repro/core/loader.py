@@ -65,6 +65,22 @@ StagingEngine lifecycle of one prefetched expert::
     (wait_all()/flush() at sequence boundaries commit leftovers without
     attributing stall)
 
+Order of one grouped decode layer ``l`` (core/engine.py): a layer's own
+misses take their pool slots before any prefetch for a later layer may::
+
+    score_and_enqueue(l, ...)      hard-pin and probe layer l's experts
+    wait(l)                        commit the prefetches that target l
+    drain_on_demand(l's misses)    admit, stage and commit l's misses
+    submit_prefetch(l + 1, ...)    reserve what is left: with no free or
+                                   evictable slot the copy is dropped
+                                   (``dropped_prefetch``), never evicting
+                                   a slot layer l holds
+    (layer l computes; layer l+1's probe finds the copy in flight, and
+    wait(l + 1) commits it)
+
+A prefetched copy whose slot is evicted, cancelled or flushed before any
+probe finds it counts in the cache's ``prefetch_unused``.
+
 Invariants: cache metadata is touched ONLY on the main thread (admission,
 reservation, downgrade cancellation all happen at submit/pump/wait time);
 the background workers see host storage and private staging buffers, never
@@ -226,7 +242,8 @@ class DynamicExpertLoader:
             if self.cache.lookup((t.layer, t.expert), is_hi) is not None:
                 continue  # raced: already resident (e.g. dup prefetch)
             slot, _evicted = self.cache.admit((t.layer, t.expert), is_hi,
-                                              current_layer)
+                                              current_layer,
+                                              prefetch=t.reason == PREFETCH)
             self.fetch_fn(t.layer, t.expert, t.precision, slot)
             self.loaded_bytes += t.bytes
             self.n_loads[t.precision] += 1
@@ -419,7 +436,8 @@ class StagingEngine:
             if not self.cache.can_admit(is_hi):
                 self.n_dropped_prefetch += 1  # slot pressure: skip, don't block
                 continue
-            slot, _ = self.cache.admit(key, is_hi, current_layer)
+            slot, _ = self.cache.admit(key, is_hi, current_layer,
+                                       prefetch=True)
             self.cache.begin_inflight(key, is_hi, slot)
             t = LoadTask(layer, int(e), int(d), PREFETCH,
                          self.loader.bytes_fn(int(d)), float(g))
@@ -527,7 +545,8 @@ class StagingEngine:
             # with the upgrade pass off (PR-4 parity) the set would only
             # accumulate dead state nothing reads until flush()
             self.lo_substituted.add(key)
-        slot, _ = self.cache.admit(key, False, self._clock_layer)
+        slot, _ = self.cache.admit(key, False, self._clock_layer,
+                                   prefetch=True)
         self.cache.begin_inflight(key, False, slot)
         t = LoadTask(job.task.layer, job.task.expert, PREC_LO, PREFETCH,
                      self.loader.bytes_fn(PREC_LO), job.task.gate)

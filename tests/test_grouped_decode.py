@@ -242,6 +242,102 @@ def test_no_duplicate_pending_predictions(setup, monkeypatch):
     assert 0 <= c <= t
 
 
+# ------------------------------------ slot order within one decode layer
+@pytest.fixture(scope="module")
+def phi16():
+    """Phi-MoE's smoke variant with its published 16 experts, top-2."""
+    cfg = smoke_variant(get_config("phi-moe"), layers=2, d_model=256,
+                        vocab=512)
+    cfg = dataclasses.replace(
+        cfg, dtype="float32",
+        moe=dataclasses.replace(cfg.moe, num_experts=16, capacity_factor=8.0))
+    m = build_model(cfg)
+    return m, m.init(jax.random.PRNGKey(0))
+
+
+def _decode_logging_admissions(m, params, ecfg, steps, seed):
+    """Greedy batch-2 decode of ``steps`` steps.  Returns the tokens, the
+    engine and, per step, every cache admission in order as (prefetch,
+    on_demand, (key, is_hi), evicted (key, is_hi) or None)."""
+    eng = OffloadEngine(m, params, ecfg)
+    admit, drain = eng.cache.admit, eng.scheduler.drain_on_demand
+    log, in_drain = [], []
+
+    def logged_admit(key, is_hi, layer, *, prefetch=False):
+        slot, evicted = admit(key, is_hi, layer, prefetch=prefetch)
+        log[-1].append((prefetch, bool(in_drain), (key, is_hi),
+                        None if evicted is None else (evicted, is_hi)))
+        return slot, evicted
+
+    def logged_drain(tasks, layer):
+        in_drain.append(layer)
+        try:
+            return drain(tasks, layer)
+        finally:
+            in_drain.pop()
+
+    eng.cache.admit = logged_admit
+    eng.scheduler.drain_on_demand = logged_drain
+    be = HobbitBackend(eng)
+    be.start_batch(2, 96)
+    prompts = np.random.default_rng(seed).integers(0, 512, (2, 16))
+    lg = be.prefill(prompts)
+    toks = []
+    for _ in range(steps):
+        log.append([])
+        toks.append(np.argmax(lg, -1).astype(np.int32))
+        lg = be.step(toks[-1])
+    return np.stack(toks), eng, log
+
+
+# the cell's engine settings; a fast modeled link keeps issue-time
+# precision downgrades (a timing decision) out of the comparison
+TIGHT = EngineConfig(hi_slots=4, lo_slots=8, thresholds=Thresholds(0.6, 0.9),
+                     link_gbps=1000.0)
+
+
+def test_layer_misses_take_their_slots_before_next_layer_prefetch(phi16):
+    """Tight pools (4 hi slots for a step demand of about 6): no on-demand
+    admission of layer l evicts a copy prefetched for a later layer in the
+    same step, so no expert is reloaded into the overflow slots, prefetches
+    that find no slot are dropped, and the tokens equal the per-expert
+    reference path's."""
+    m, params = phi16
+    toks, eng, log = _decode_logging_admissions(m, params, TIGHT, 24, 3)
+    for step in log:
+        prefetched = set()
+        for prefetch, on_demand, entry, evicted in step:
+            if prefetch:
+                prefetched.add(entry)
+            elif on_demand and evicted is not None:
+                assert not (evicted in prefetched
+                            and evicted[0][0] > entry[0][0]), step
+    s = eng.stats()
+    assert s["union_reloads"] == 0
+    assert s["dropped_prefetch"] > 0               # the order engaged
+    assert s["precision_downgrades"] == 0
+    ref, _, _ = _decode_logging_admissions(m, params, _reference(TIGHT), 24,
+                                           3)
+    np.testing.assert_array_equal(toks, ref)
+
+
+def test_pools_holding_every_expert_ship_what_the_reference_ships(phi16):
+    """Bypass control: pools that hold every expert of both layers never
+    evict or drop, so the order of admissions changes nothing: the staged
+    path commits the bytes the synchronous reference path commits (each
+    expert it needs, once) and serves the same tokens."""
+    m, params = phi16
+    roomy = dataclasses.replace(TIGHT, hi_slots=32, lo_slots=32)
+    toks, eng, _ = _decode_logging_admissions(m, params, roomy, 12, 5)
+    ref, ref_eng, _ = _decode_logging_admissions(m, params,
+                                                 _reference(roomy), 12, 5)
+    s, r = eng.stats(), ref_eng.stats()
+    assert s["cache"]["evictions"] == 0 and s["dropped_prefetch"] == 0
+    assert s["prefetch_unused"] == 0
+    assert s["commit_shipped_bytes"] == r["commit_shipped_bytes"] > 0
+    np.testing.assert_array_equal(toks, ref)
+
+
 # ------------------------------------------------ cache corner cases
 def test_union_overflow_reload_stays_correct_at_batch2(setup):
     """Cache smaller than the layer's union demand at batch 2: same-layer

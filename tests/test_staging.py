@@ -426,6 +426,67 @@ def test_cancel_inflight_returns_slot_and_keeps_other_precision():
     assert c.cancel_inflight((0, 7), True) is None  # idempotent
 
 
+# ------------------------------------------------- prefetch use accounting
+def test_prefetch_evicted_before_any_probe_counts_once():
+    """A landed prefetched copy that an admission evicts before any probe
+    found it counts once in prefetch_unused; evicting a copy that was
+    loaded on demand counts nothing."""
+    eng, cache, _, _ = _unit_engine(hi_slots=1)
+    eng.submit_prefetch(1, [0], np.array([PREC_HI]), current_layer=0)
+    eng.wait(1)
+    _, evicted = cache.admit((0, 5), True, 0)
+    assert evicted == (1, 0)
+    assert cache.prefetch_unused == 1
+    _, evicted = cache.admit((0, 6), True, 0)
+    assert evicted == (0, 5)
+    cache.new_sequence()
+    assert cache.prefetch_unused == 1
+    eng.shutdown()
+
+
+def test_prefetch_probed_in_flight_counts_as_used():
+    """A probe that finds the prefetched slot while its copy is still in
+    flight is a use: neither the later eviction nor a flush counts it."""
+    eng, cache, _, _ = _unit_engine(hi_slots=1, stage_sleep=0.05)
+    eng.submit_prefetch(1, [0], np.array([PREC_HI]), current_layer=0)
+    assert cache.is_inflight((1, 0), True)
+    assert cache.probe((1, 0), True) is not None
+    eng.wait(1)
+    cache.admit((0, 5), True, 0)
+    cache.new_sequence()
+    assert cache.prefetch_unused == 0
+    eng.shutdown()
+
+
+def test_prefetch_cancelled_by_a_downgrade_counts_once():
+    """The hi reservation a downgrade cancels counts once; the lo stand-in
+    the compute path serves in its place (``note_use``) and the hi copy a
+    probe found count nothing, even after a flush."""
+    eng, cache, _, _ = _unit_engine(link_bps=1e6, stage_sleep=0.05)
+    _downgrade_one(eng, cache)
+    assert cache.prefetch_unused == 1
+    assert cache.probe((1, 0), True) is not None
+    cache.note_use((1, 1), False)
+    cache.new_sequence()
+    assert cache.prefetch_unused == 1
+    eng.shutdown()
+
+
+def test_prefetch_flushed_at_new_sequence_counts_once():
+    """A prefetched copy still unprobed when the sequence ends counts once,
+    at the first new_sequence only."""
+    eng, cache, _, _ = _unit_engine()
+    eng.submit_prefetch(1, [0, 1], np.array([PREC_HI, PREC_LO]),
+                        current_layer=0)
+    eng.flush()
+    assert cache.probe((1, 0), True) is not None
+    cache.new_sequence()
+    assert cache.prefetch_unused == 1               # (1, 1) lo, never probed
+    cache.new_sequence()
+    assert cache.prefetch_unused == 1
+    eng.shutdown()
+
+
 # ------------------------------------------------------------- teardown
 def test_engine_close_idempotent_and_step_raises(setup):
     m, params = setup
@@ -485,7 +546,8 @@ def test_engine_stats_json_roundtrip_with_stream_fields(setup):
     generate(HobbitBackend(eng), np.array([[1, 2, 3]]), 4, max_len=16)
     s = eng.stats()
     back = _roundtrip_same_keys(s)
-    for f in NEW_FIELDS + ("streams", "link_gbps"):
+    for f in NEW_FIELDS + ("streams", "link_gbps", "prefetch_unused",
+                           "dropped_prefetch"):
         assert f in back, f
     assert back["streams"] == 2
     assert isinstance(back["per_stream_bytes"], list)
